@@ -49,7 +49,7 @@ import numpy as np
 from repro.core import server_shard
 from repro.core.rules import ServerConfig
 from repro.data.mnist import load_mnist
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.models.mlp import init_mlp, nll_loss
 from repro.sim.fred import SimConfig, build_step_fn, init_sim
 
@@ -82,7 +82,7 @@ def measure(params, ds, cfg, *, n_windows, reps, seed=0):
     S = cfg.server_shards
     state = init_sim(cfg, params)
     if S > 1:
-        mesh = make_mesh_compat((S,), (cfg.server_axis,))
+        mesh = make_mesh((S,), (cfg.server_axis,))
         server_shard.validate_server_mesh(mesh, S, cfg.server_axis)
         state = state._replace(server=server_shard.shard_server_state(
             state.server, mesh, cfg.server_axis))

@@ -4,8 +4,10 @@ The FRED serial path carries the repo's strongest correctness contract:
 bitwise determinism from the seed, K-invariance, and bitwise identity with
 the pre-engine-refactor simulator.  This script freezes that contract into
 small npz files under ``tests/goldens/`` — one per config — which
-``tests/test_goldens.py`` replays *bitwise* in CI (across the jax version
-matrix; diffs are uploaded as artifacts on failure).
+``tests/test_goldens.py`` replays *bitwise* in CI (diffs are uploaded as
+artifacts on failure).  Each file records the ``jax.__version__`` it was
+captured under: XLA numerics and the default PRNG stream change across jax
+releases, so a golden replays only on the version that wrote it.
 
 Regenerate after an *intentional* trajectory change:
 
@@ -141,12 +143,13 @@ def main():
     os.makedirs(GOLDEN_DIR, exist_ok=True)
     for name, cfg in golden_configs().items():
         arrays = run_config(cfg)
+        arrays["jax_version"] = np.str_(jax.__version__)
         path = os.path.join(GOLDEN_DIR, f"{name}.npz")
         np.savez_compressed(path, **arrays)
         print(f"  captured {name}: {os.path.getsize(path) / 1024:.0f} KB "
               f"(T={int(arrays['final_timestamp'])}, "
               f"val={arrays['val_cost'][-1]:.6f})")
-    print(f"goldens written to {GOLDEN_DIR}")
+    print(f"goldens written to {GOLDEN_DIR} (jax {jax.__version__})")
 
 
 if __name__ == "__main__":

@@ -406,7 +406,8 @@ def serial_apply(scfg: ServerConfig, server: ServerState, grads, push,
 # ---------------------------------------------------------------------------
 
 def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
-                client_ts, client_params=None):
+                client_ts, client_params=None, *, mesh=None,
+                server_axis: str = "server"):
     """One masked-sum application of all pushed gradients (beyond-paper).
 
     `grads` leaves are [K, ...] over the matching `server.params` leaves;
@@ -429,6 +430,11 @@ def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
     int32 pytree with [K] leaves (per-tensor staleness — each tensor's τ is
     measured from its own last synchronization; the per-leaf τ reaches the
     batched Pallas kernel as that leaf's SMEM τ vector).
+
+    `mesh` is the device mesh the state is placed on, if any: the kernel
+    then applies each leaf's `server_axis` block on the device that holds
+    it (`core/server_shard.py` routing; every leaf replicated when the mesh
+    has no such axis).
 
     Returns (server, taus [K] — the per-event staleness, averaged over
     leaves in per-tensor mode).
@@ -523,6 +529,12 @@ def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
         # mean pushed gradient, so the leaf never round-trips HBM between
         # the statistics step and the delta.
         from repro.kernels.ops import fused_event_apply
+        leaf_specs = None
+        if mesh is not None:
+            from repro.core import server_shard
+            S = server_shard.mesh_axis_size(mesh, server_axis)
+            leaf_specs = [server_shard.server_leaf_spec(p.shape, S, server_axis)
+                          for p in jax.tree.leaves(server.params)]
         if rule.batched_pallas_mode == "coeff":
             w_leaves = [rule.fused_coeffs(scfg, t) * m
                         for t, m in zip(t_leaves, m_leaves)]
@@ -547,7 +559,8 @@ def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
             variant=scfg.variant, mode=rule.batched_pallas_mode,
             track_stats=kernel_stats,
             block_rows=scfg.kernel_block_rows,
-            interpret=scfg.kernel_interpret)
+            interpret=scfg.kernel_interpret, mesh=mesh,
+            leaf_specs=leaf_specs)
         if kernel_stats:
             cast = lambda new, old: jax.tree.map(
                 lambda a, o: a.astype(o.dtype), new, old)

@@ -164,10 +164,13 @@ def build_round_step(
     grad_fn: Callable,     # grad_fn(params, batch) -> (loss, grads)
     apply_mode: str = "serial",
     batched_loss_fn: Callable = None,   # batched(W, deltas, batch) -> [C]
+    mesh=None,
 ):
     """Returns round_step(state, batch, key) -> (state, metrics).
 
     `batch` leaves must have a leading [C] axis (one shard per client group).
+    `mesh` is the mesh `shard_round_state` placed the server on, if any; the
+    one-kernel apply then runs each shard's block on its own device.
 
     With ``apply_mode='fused'`` and ``tc.fused_mode`` 'auto'/'cotangent' the
     per-client gradients are reduced by the engine's cotangent path when the
@@ -385,7 +388,8 @@ def build_round_step(
             else:
                 server, taus = engine.fused_apply(
                     scfg, state.server, qbatch.payload["grad"], q_push,
-                    q_ts, client_params=q_cp)
+                    q_ts, client_params=q_cp, mesh=mesh,
+                    server_axis=tc.server_axis)
             mean_tau = (jnp.sum(qbatch.valid.astype(jnp.float32) * taus)
                         / jnp.maximum(k_eff, 1))
         elif use_cotangent:
@@ -404,7 +408,7 @@ def build_round_step(
         else:
             server, taus = engine.fused_apply(
                 scfg, state.server, grads, push, grad_ts,
-                state.client_params)
+                state.client_params, mesh=mesh, server_axis=tc.server_axis)
         if not use_queue:
             mean_tau = jnp.mean(taus)
 

@@ -15,6 +15,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.kernels import batched_update as _bk
 from repro.kernels import fasgd_update as _fk
@@ -187,7 +188,8 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
                       weights, wmean, taus, has_push, *, lr,
                       gamma=0.9, beta=0.9, eps=1e-8, variant="intent",
                       mode="fasgd", track_stats=True, block_rows: int = 0,
-                      interpret: bool | None = None):
+                      interpret: bool | None = None, mesh=None,
+                      leaf_specs=None):
     """One-kernel K-event server apply over arbitrary pytrees.
 
     Per leaf, ONE launch of `fused_event_apply.fused_event_apply_2d`
@@ -204,6 +206,14 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     engine casts); returns (params', n', b', v') with statistics in
     float32.  `block_rows=0` uses the per-K tuned table
     (`default_block_rows`); `interpret` dispatches per `_fused_event_path`.
+
+    The compiler cannot partition a Mosaic kernel, so under a device `mesh`
+    each leaf's launch runs inside `jax.shard_map`: every device applies
+    the block of the leaf that its `leaf_specs` entry (one PartitionSpec
+    per leaf, flatten order; default replicated) assigns it, the K
+    gradients split the same way on their trailing dims, and the [K]
+    vectors replicated.  The kernel is elementwise over the leaf, so a
+    block applies on its own.
     """
     path = _fused_event_path(interpret)
     K = jax.tree.leaves(grads)[0].shape[0]
@@ -223,12 +233,10 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     w_l, wm_l, t_l, hp_l = (per_leaf(weights), per_leaf(wmean),
                             per_leaf(taus), per_leaf(has_push))
 
-    def one(p, g, nn, bb, vv, w, wm, t, hp):
-        kw = dict(gamma=gamma, beta=beta, eps=eps, variant=variant,
-                  mode=mode, track_stats=track_stats)
-        if path == "xla":
-            return fused_event_apply_ref(p, g, nn, bb, vv, w, wm, t, lr, hp,
-                                         **kw)
+    kw = dict(gamma=gamma, beta=beta, eps=eps, variant=variant, mode=mode,
+              track_stats=track_stats)
+
+    def launch(p, g, nn, bb, vv, w, wm, t, hp):
         shape, dtype = p.shape, p.dtype
         (p2, _), (n2, _), (b2, _), (v2, _) = (
             _pad_to_tiles(p, rows), _pad_to_tiles(nn, rows),
@@ -246,10 +254,26 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
         unpad = lambda a: a.reshape(-1)[:size].reshape(shape)
         return unpad(po).astype(dtype), unpad(no), unpad(bo), unpad(vo)
 
+    def one(p, g, nn, bb, vv, w, wm, t, hp, spec):
+        if path == "xla":
+            return fused_event_apply_ref(p, g, nn, bb, vv, w, wm, t, lr, hp,
+                                         **kw)
+        if mesh is None:
+            return launch(p, g, nn, bb, vv, w, wm, t, hp)
+        rep = PartitionSpec()
+        gspec = PartitionSpec(None, *spec)
+        return jax.shard_map(
+            launch, mesh=mesh,
+            in_specs=(spec, gspec, spec, spec, spec, rep, rep, rep, rep),
+            out_specs=(spec,) * 4, check_vma=False,
+        )(p, g, nn, bb, vv, w, wm, t, hp)
+
+    if leaf_specs is None:
+        leaf_specs = [PartitionSpec()] * params_def.num_leaves
     outs = [one(*leaves) for leaves in zip(
         jax.tree.leaves(params), jax.tree.leaves(grads),
         jax.tree.leaves(n), jax.tree.leaves(b), jax.tree.leaves(v),
-        w_l, wm_l, t_l, hp_l)]
+        w_l, wm_l, t_l, hp_l, leaf_specs)]
     unzip = tuple(jax.tree.unflatten(params_def, [o[i] for o in outs])
                   for i in range(4))
     return unzip  # (params, n, b, v)

@@ -4,21 +4,11 @@ devices *before* any jax init; tests must keep seeing 1 device)."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit sharding mode needs the axis type spelled out
-    from jax.sharding import AxisType
-except ImportError:  # jax <= 0.4.x: no AxisType; every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` with Auto axis types on every jax that runs here.
-
-    Older jax (< 0.5) has neither `AxisType` nor the `axis_types` kwarg and
-    treats all axes as Auto already, so the kwarg is simply dropped.
-    """
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis Auto (GSPMD-partitioned placement)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -26,7 +16,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -34,7 +24,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_server_mesh(server: int = 1, data: int = 1):
@@ -50,7 +40,7 @@ def make_server_mesh(server: int = 1, data: int = 1):
     n = len(jax.devices())
     server = max(1, min(server, n))
     data = max(1, min(data, n // server))
-    return make_mesh_compat((server, data), ("server", "data"))
+    return make_mesh((server, data), ("server", "data"))
 
 
 def init_distributed_mesh(server: int = 1, *, coordinator_address=None,
@@ -65,13 +55,10 @@ def init_distributed_mesh(server: int = 1, *, coordinator_address=None,
     this degrades to the single-process `make_server_mesh` — which is also
     the simulated multi-host path (`XLA_FLAGS`, docs/SHARDING.md recipe).
     """
-    if coordinator_address is not None:
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes, process_id=process_id)
-        except RuntimeError:
-            pass  # already initialized — keep the existing process group
+    if coordinator_address is not None and not jax.distributed.is_initialized():
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes, process_id=process_id)
     return make_server_mesh(server=server)
 
 

@@ -27,6 +27,7 @@ from repro.core import server_shard
 from repro.core.round_trainer import (
     build_round_step, init_round_state, shard_round_state)
 from repro.data.tokens import TokenDataConfig, make_batch as make_token_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_server_mesh
 from repro.launch.steps import make_train_step, server_config
 from repro.models.api import make_batch, param_count
@@ -43,6 +44,140 @@ def batch_for_step(cfg, B, S, step):
     tcfg = TokenDataConfig(vocab_size=cfg.vocab_size, seq_len=S, batch_size=B)
     tokens, targets = make_token_batch(tcfg, step)
     return {"tokens": tokens, "targets": targets}
+
+
+def run_round_trainer(cfg, tc: TrainerConfig, params, *, apply_mode: str,
+                      steps: int, batch: int, seq: int, log_every: int = 10,
+                      ckpt_dir: str = "", ckpt_every: int = 0,
+                      scenario_name: str = "off") -> dict:
+    """Round-trainer training loop (the CLI's ``--clients C > 0`` mode).
+
+    Builds the round state for ``tc`` around ``params``, shards the server
+    when ``tc.server_shards > 1``, compiles one round step ahead of time,
+    and runs rounds ``[start, steps)`` on synthetic batches of ``batch``
+    sequences of ``seq`` tokens, split over the ``tc.num_round_clients``
+    client groups (``start`` > 0 when resuming from ``ckpt_dir``).  Prints
+    the CLI's log lines, the compile time apart from tracing and lowering
+    (what a persistent-cache hit saves), and returns ``{"state", "losses",
+    "compiled"}``: ``losses`` holds one mean loss per round run, and
+    ``compiled`` is the AOT-compiled step (``memory_analysis()``,
+    ``as_text()``).
+    """
+    def grad_fn(p, b):
+        (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(p, cfg, b)
+        return loss, g
+
+    # token archs get the shared/delta event-batched loss so the fused
+    # cotangent reduction applies to the transformer stack (models/lm.py);
+    # audio/vlm batches carry extra modal keys the adapter doesn't thread.
+    batched_loss_fn = None
+    if cfg.arch_type not in ("audio", "vlm"):
+        lm_loss = make_lm_loss(cfg)
+
+        def batched_loss_fn(W, deltas, b):
+            return lm_loss.event_batched(W, deltas, b["tokens"], b["targets"])
+
+    C = tc.num_round_clients
+    if batch % C:
+        raise ValueError(f"global batch {batch} must divide over {C} clients")
+    Bc = batch // C
+
+    state = init_round_state(tc, params)
+    smesh = None
+    if tc.server_shards > 1:
+        smesh = make_server_mesh(server=tc.server_shards)
+        server_shard.validate_server_mesh(
+            smesh, tc.server_shards, tc.server_axis)
+        state = shard_round_state(state, smesh, tc.server_axis)
+        print(f"[train] server sharded: {tc.server_shards} shards on "
+              f"axis '{tc.server_axis}' (mesh {dict(smesh.shape)})")
+
+    start = 0
+    if ckpt_dir and latest_step(ckpt_dir) is not None:
+        state, start, _ = restore_checkpoint(ckpt_dir, state)
+        print(f"[train] resumed from step {start}")
+
+    def client_batch(step):
+        flat = batch_for_step(cfg, batch, seq, step)
+        return jax.tree.map(lambda l: l.reshape((C, Bc) + l.shape[1:]), flat)
+
+    def round_key(step):
+        return jax.random.fold_in(jax.random.PRNGKey(tc.seed), step)
+
+    t0 = time.perf_counter()
+    lowered = jax.jit(build_round_step(
+        tc, grad_fn, apply_mode=apply_mode, batched_loss_fn=batched_loss_fn,
+        mesh=smesh,
+    )).lower(state, client_batch(start), round_key(start))
+    t1 = time.perf_counter()
+    step_fn = lowered.compile()
+    compile_s = time.perf_counter() - t1
+    print(f"[train] round step traced and lowered in {t1 - t0:.2f}s, "
+          f"compiled in {compile_s:.2f}s")
+
+    losses = []
+    t0 = time.perf_counter()
+    for step in range(start, steps):
+        state, m = step_fn(state, client_batch(step), round_key(step))
+        losses.append(m["loss"])
+        if step % log_every == 0 or step == steps - 1:
+            wall = (f" wall={float(m['wall']):.2f}"
+                    if "wall" in m else "")
+            print(f"  step {step:5d} loss={float(m['loss']):.4f} "
+                  f"tau={float(m['mean_tau']):.2f} "
+                  f"push={int(m['pushes'])}/{C} fetch={int(m['fetches'])}/{C} "
+                  f"T={int(m['timestamp'])}{wall}")
+        if ckpt_every and ckpt_dir and (step + 1) % ckpt_every == 0:
+            save_checkpoint(ckpt_dir, step + 1, state)
+    losses = [float(l) for l in losses]
+    dt = time.perf_counter() - t0
+    print(f"[train] done: {steps - start} rounds in {dt:.1f}s "
+          f"({(steps - start) / max(dt, 1e-9):.2f} rounds/s)")
+    cnt = state.counters
+    sent = float(cnt.push_bytes_sent + cnt.fetch_bytes_sent)
+    total = float(cnt.push_bytes_total + cnt.fetch_bytes_total)
+    if total > 0:
+        print(f"[train] bandwidth: {sent / 2**20:.1f} MiB sent of "
+              f"{total / 2**20:.1f} MiB potential "
+              f"({sent / total:.1%} transmitted, "
+              f"{total / max(sent, 1e-9):.1f}x reduction)")
+    if tc.queue_capacity:
+        w = max(int(cnt.queue_windows), 1)
+        print(f"[train] queue: {int(cnt.queue_drained)} drained / "
+              f"{int(cnt.queue_enqueued)} admitted "
+              f"({int(cnt.queue_rejected)} rejected, "
+              f"{int(cnt.queue_dropped)} dropped), "
+              f"mean depth {float(cnt.queue_depth_sum) / w:.2f}, "
+              f"peak {int(cnt.queue_depth_peak)}, "
+              f"mean latency "
+              f"{float(cnt.queue_latency_sum) / max(int(cnt.queue_drained), 1):.2f} T-ticks")
+    if tc.use_fused_kernel:
+        n_leaves = len(jax.tree.leaves(state.server.params))
+        launches = int(cnt.kernel_launches)
+        windows = launches // max(n_leaves, 1)
+        events = int(cnt.kernel_events)
+        print(f"[train] kernel: {launches} launches "
+              f"({windows} apply windows x {n_leaves} leaves), "
+              f"{events} events consumed "
+              f"({events / max(windows, 1):.1f} events/window)")
+    if tc.server_shards > 1:
+        print(f"[train] shards: {tc.server_shards} server shards, "
+              f"{int(cnt.shard_events)} events over "
+              f"{int(cnt.shard_applies)} apply windows "
+              f"(peak window batch {int(cnt.shard_depth_peak)}), "
+              f"peak resident "
+              f"{float(cnt.shard_bytes_peak) / 2**20:.2f} MiB/shard")
+    if tc.scenario is not None:
+        rounds = max(int(cnt.scenario_windows), 1)
+        k_used = (tc.kasync_k or C) if server_rules.get_rule(
+            tc.rule).synchronous else C
+        print(f"[train] scenario '{scenario_name}': "
+              f"wall={float(cnt.wall_clock):.2f} "
+              f"({float(cnt.wall_clock) / rounds:.3f}/round, "
+              f"barrier {k_used}/{C}), "
+              f"mean active {float(cnt.scenario_active_sum) / rounds:.1f}"
+              f"/{C} over {rounds} rounds")
+    return {"state": state, "losses": losses, "compiled": step_fn}
 
 
 def main():
@@ -120,6 +255,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     scn = (None if args.scenario == "off"
@@ -155,105 +291,12 @@ def main():
     print(f"[train] {cfg.name}: {param_count(params):,} params, "
           f"rule={args.rule}, clients={args.clients}, mesh={mesh.shape}")
 
-    def grad_fn(p, batch):
-        (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(p, cfg, batch)
-        return loss, g
-
-    # token archs get the shared/delta event-batched loss so the fused
-    # cotangent reduction applies to the transformer stack (models/lm.py);
-    # audio/vlm batches carry extra modal keys the adapter doesn't thread.
-    batched_loss_fn = None
-    if cfg.arch_type not in ("audio", "vlm"):
-        lm_loss = make_lm_loss(cfg)
-
-        def batched_loss_fn(W, deltas, batch):
-            return lm_loss.event_batched(
-                W, deltas, batch["tokens"], batch["targets"])
-
     if args.clients > 0:
-        state = init_round_state(tc, params)
-        if tc.server_shards > 1:
-            smesh = make_server_mesh(server=tc.server_shards)
-            server_shard.validate_server_mesh(
-                smesh, tc.server_shards, tc.server_axis)
-            state = shard_round_state(state, smesh, tc.server_axis)
-            print(f"[train] server sharded: {tc.server_shards} shards on "
-                  f"axis '{tc.server_axis}' (mesh {dict(smesh.shape)})")
-        step_fn = jax.jit(build_round_step(
-            tc, grad_fn, apply_mode=args.apply_mode,
-            batched_loss_fn=batched_loss_fn))
-        C = args.clients
-        assert args.batch % C == 0, "global batch must divide clients"
-        Bc = args.batch // C
-
-        start = 0
-        if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
-            state, start, _ = restore_checkpoint(args.ckpt_dir, state)
-            print(f"[train] resumed from step {start}")
-
-        t0 = time.time()
-        for step in range(start, args.steps):
-            flat = batch_for_step(cfg, args.batch, args.seq, step)
-            batch = jax.tree.map(
-                lambda l: l.reshape((C, Bc) + l.shape[1:]), flat)
-            state, m = step_fn(state, batch, jax.random.fold_in(
-                jax.random.PRNGKey(args.seed), step))
-            if step % args.log_every == 0 or step == args.steps - 1:
-                wall = (f" wall={float(m['wall']):.2f}"
-                        if "wall" in m else "")
-                print(f"  step {step:5d} loss={float(m['loss']):.4f} "
-                      f"tau={float(m['mean_tau']):.2f} "
-                      f"push={int(m['pushes'])}/{C} fetch={int(m['fetches'])}/{C} "
-                      f"T={int(m['timestamp'])}{wall}")
-            if args.ckpt_every and args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-                save_checkpoint(args.ckpt_dir, step + 1, state)
-        dt = time.time() - t0
-        print(f"[train] done: {args.steps - start} rounds in {dt:.1f}s "
-              f"({(args.steps - start) / max(dt, 1e-9):.2f} rounds/s)")
-        cnt = state.counters
-        sent = float(cnt.push_bytes_sent + cnt.fetch_bytes_sent)
-        total = float(cnt.push_bytes_total + cnt.fetch_bytes_total)
-        if total > 0:
-            print(f"[train] bandwidth: {sent / 2**20:.1f} MiB sent of "
-                  f"{total / 2**20:.1f} MiB potential "
-                  f"({sent / total:.1%} transmitted, "
-                  f"{total / max(sent, 1e-9):.1f}x reduction)")
-        if args.queue_capacity:
-            w = max(int(cnt.queue_windows), 1)
-            print(f"[train] queue: {int(cnt.queue_drained)} drained / "
-                  f"{int(cnt.queue_enqueued)} admitted "
-                  f"({int(cnt.queue_rejected)} rejected, "
-                  f"{int(cnt.queue_dropped)} dropped), "
-                  f"mean depth {float(cnt.queue_depth_sum) / w:.2f}, "
-                  f"peak {int(cnt.queue_depth_peak)}, "
-                  f"mean latency "
-                  f"{float(cnt.queue_latency_sum) / max(int(cnt.queue_drained), 1):.2f} T-ticks")
-        if args.use_fused_kernel:
-            n_leaves = len(jax.tree.leaves(state.server.params))
-            launches = int(cnt.kernel_launches)
-            windows = launches // max(n_leaves, 1)
-            events = int(cnt.kernel_events)
-            print(f"[train] kernel: {launches} launches "
-                  f"({windows} apply windows x {n_leaves} leaves), "
-                  f"{events} events consumed "
-                  f"({events / max(windows, 1):.1f} events/window)")
-        if tc.server_shards > 1:
-            print(f"[train] shards: {tc.server_shards} server shards, "
-                  f"{int(cnt.shard_events)} events over "
-                  f"{int(cnt.shard_applies)} apply windows "
-                  f"(peak window batch {int(cnt.shard_depth_peak)}), "
-                  f"peak resident "
-                  f"{float(cnt.shard_bytes_peak) / 2**20:.2f} MiB/shard")
-        if scn is not None:
-            rounds = max(int(cnt.scenario_windows), 1)
-            k_used = (tc.kasync_k or C) if server_rules.get_rule(
-                args.rule).synchronous else C
-            print(f"[train] scenario '{args.scenario}': "
-                  f"wall={float(cnt.wall_clock):.2f} "
-                  f"({float(cnt.wall_clock) / rounds:.3f}/round, "
-                  f"barrier {k_used}/{C}), "
-                  f"mean active {float(cnt.scenario_active_sum) / rounds:.1f}"
-                  f"/{C} over {rounds} rounds")
+        run_round_trainer(
+            cfg, tc, params, apply_mode=args.apply_mode, steps=args.steps,
+            batch=args.batch, seq=args.seq, log_every=args.log_every,
+            ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
+            scenario_name=args.scenario)
     else:
         scfg = server_config(tc)
         state = server_rules.init(scfg, params)

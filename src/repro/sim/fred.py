@@ -427,7 +427,7 @@ def _dispatch(config: SimConfig, rr_pos, key, het_logits):
 
 
 def _build_queue_step(config: SimConfig, loss_fn, data_x, data_y, K,
-                      batched_loss_fn=None):
+                      batched_loss_fn=None, mesh=None):
     """step(state, keys) for the queued protocol: one drain window per call.
 
     K arrivals (dispatch → stale-copy gradient → eq.-9 push gate →
@@ -571,7 +571,7 @@ def _build_queue_step(config: SimConfig, loss_fn, data_x, data_y, K,
         elif config.apply_mode == "fused":
             new_server, taus = engine.fused_apply(
                 scfg, state.server, batch.payload["grad"], push_arg, grad_ts,
-                client_params=cp)
+                client_params=cp, mesh=mesh, server_axis=config.server_axis)
             dlosses = batch.payload["loss"]
         else:
             new_server, taus = engine.serial_apply(
@@ -742,7 +742,7 @@ def build_step_fn(
                 "yet — run the queued simulation unsharded")
         return _build_queue_step(
             config, loss_fn, data_x, data_y, K,
-            batched_loss_fn=batched_loss_fn)
+            batched_loss_fn=batched_loss_fn, mesh=mesh)
 
     def event_body(state: SimState, inp):
         """One client event — the paper's protocol, verbatim.
@@ -928,13 +928,12 @@ def build_step_fn(
         if use_cotangent else None)
     vgrad = jax.vmap(grad_fn)
     if client_mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
         spec = PartitionSpec(client_axis)
-        vgrad = shard_map(
+        vgrad = jax.shard_map(
             jax.vmap(grad_fn), mesh=client_mesh,
             in_specs=(spec, spec, spec), out_specs=(spec, spec),
-            check_rep=False)
+            check_vma=False)
 
     def step(state: SimState, keys):
         ks = jax.vmap(lambda k: jax.random.split(k, 4))(keys)    # [K, 4, ...]
@@ -1021,14 +1020,14 @@ def build_step_fn(
                      else tree_where_axis(push, grads, cache_e))
             new_server, taus = engine.fused_apply(
                 scfg, state.server, g_eff, jnp.ones((K,), bool), grad_ts,
-                client_params=p_e)
+                client_params=p_e, mesh=mesh, server_axis=config.server_axis)
             grad_cache = engine.last_event_scatter(
                 state.grad_cache, cs, grads, push, lam)
         else:
             losses, grads = vgrad(p_e, xb, yb)
             new_server, taus = engine.fused_apply(
                 scfg, state.server, grads, push, grad_ts,
-                client_params=p_e)
+                client_params=p_e, mesh=mesh, server_axis=config.server_axis)
             grad_cache = None
 
         # --- fetch gates (post-apply server state) ---
@@ -1160,23 +1159,20 @@ def run_simulation(
                 state.queue, mesh, config.server_axis))
     K = config.events_per_step
     base = jax.random.PRNGKey(config.seed)
+    data_x, data_y = jnp.asarray(data_x), jnp.asarray(data_y)
 
-    step_fns = {}
-
-    def get_step(k_events):
-        if k_events not in step_fns:
-            step_fns[k_events] = build_step_fn(
-                config, loss_fn, data_x, data_y, events=k_events,
-                mesh=mesh, client_axis=client_axis,
-                batched_loss_fn=batched_loss_fn)
-        return step_fns[k_events]
-
+    # the dataset is an argument, not a closed-over constant: a constant
+    # would be baked into the executable (and every persistent-cache entry)
     @functools.partial(jax.jit, static_argnames=("n_batches", "k_events"))
-    def run_span(state, start_event, n_batches, k_events):
+    def run_span(state, start_event, data_x, data_y, n_batches, k_events):
         keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
             start_event + jnp.arange(n_batches * k_events))
         keys = keys.reshape((n_batches, k_events) + keys.shape[1:])
-        return jax.lax.scan(get_step(k_events), state, keys)
+        step = build_step_fn(config, loss_fn, data_x, data_y,
+                             events=k_events, mesh=mesh,
+                             client_axis=client_axis,
+                             batched_loss_fn=batched_loss_fn)
+        return jax.lax.scan(step, state, keys)
 
     eval_jit = jax.jit(eval_fn) if eval_fn is not None else None
 
@@ -1191,12 +1187,14 @@ def run_simulation(
         span = min(eval_every, num_steps - done)
         n_batches, rem = divmod(span, K)
         if n_batches:
-            state, metrics = run_span(state, jnp.int32(done), n_batches, K)
+            state, metrics = run_span(state, jnp.int32(done), data_x, data_y,
+                                      n_batches, K)
             if collect_step_metrics:
                 collect(metrics)
             done += n_batches * K
         if rem:
-            state, metrics = run_span(state, jnp.int32(done), 1, rem)
+            state, metrics = run_span(state, jnp.int32(done), data_x, data_y,
+                                      1, rem)
             if collect_step_metrics:
                 collect(metrics)
             done += rem

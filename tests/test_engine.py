@@ -220,8 +220,8 @@ def test_counters_shared_between_fred_and_round_trainer(setup):
 def test_shard_map_fleet_runs_on_host_mesh(setup):
     """Optional client-axis sharding: a 1-device 'clients' mesh must produce
     the same fused trajectory as the unsharded run."""
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("clients",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("clients",))
     params, ds, loss = setup
     cfg = dataclasses.replace(
         _cfg("fasgd", num_clients=8), events_per_step=4, apply_mode="fused")

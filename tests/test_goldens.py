@@ -5,16 +5,20 @@ scripts/capture_goldens.py).
 This is the engine's strongest no-regression net: it catches any change to
 the serial protocol order, RNG stream, or numerics — including ones that
 would silently pass allclose-level tests.  On failure the mismatching
-arrays are dumped to ``$GOLDEN_DIFF_DIR`` (default ``tests/goldens_diffs``)
-so CI can upload them as artifacts for offline inspection.
+arrays are dumped to ``$GOLDEN_DIFF_DIR`` (default: a fresh temporary
+directory) so CI can upload them as artifacts for offline inspection.
 
-If a trajectory change is *intentional*, regenerate with
+Each golden records the ``jax.__version__`` that captured it; a replay under
+another version fails up front, naming both, since XLA numerics and the
+default PRNG stream move between releases.  If a trajectory change is
+*intentional*, or jax was upgraded, regenerate with
 
     PYTHONPATH=src python scripts/capture_goldens.py
 """
 import glob
 import importlib.util
 import os
+import tempfile
 
 import jax
 import numpy as np
@@ -22,8 +26,6 @@ import pytest
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 GOLDEN_DIR = os.path.join(_HERE, "goldens")
-DIFF_DIR = os.environ.get(
-    "GOLDEN_DIFF_DIR", os.path.join(_HERE, "goldens_diffs"))
 
 
 def _load_capture_module():
@@ -57,9 +59,17 @@ def test_golden_trajectory_bitwise(name):
         f"stale golden {name}.npz: config no longer in the capture grid")
     got = capture.run_config(configs[name])
     want = np.load(os.path.join(GOLDEN_DIR, f"{name}.npz"))
+    assert "jax_version" in want.files, (
+        f"{name}.npz records no jax version: re-run scripts/capture_goldens.py")
+    captured = str(want["jax_version"])
+    assert captured == jax.__version__, (
+        f"golden {name} was captured under jax {captured} but jax "
+        f"{jax.__version__} is installed: re-run scripts/capture_goldens.py")
 
     mismatches = {}
     for key in want.files:
+        if key == "jax_version":
+            continue
         g = np.asarray(got[key])
         w = want[key]
         if g.shape != w.shape or not np.array_equal(g, w):
@@ -68,12 +78,14 @@ def test_golden_trajectory_bitwise(name):
     assert not extra, f"{name}: arrays missing from golden: {sorted(extra)}"
 
     if mismatches:
-        os.makedirs(DIFF_DIR, exist_ok=True)
+        diff_dir = (os.environ.get("GOLDEN_DIFF_DIR")
+                    or tempfile.mkdtemp(prefix="golden_diffs_"))
+        os.makedirs(diff_dir, exist_ok=True)
         dump = {}
         for key, (w, g) in mismatches.items():
             dump[f"want_{key}"] = w
             dump[f"got_{key}"] = np.asarray(g)
-        diff_path = os.path.join(DIFF_DIR, f"{name}.npz")
+        diff_path = os.path.join(diff_dir, f"{name}.npz")
         np.savez_compressed(diff_path, **dump)
         detail = {
             k: (f"max|Δ|={np.max(np.abs(w.astype(np.float64) - np.asarray(g, np.float64))):.3e}"

@@ -413,14 +413,14 @@ def test_round_trainer_queue_validation():
 
 
 def test_queue_rejects_client_axis_mesh(setup):
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     params, ds, loss = setup
     cfg = dataclasses.replace(
         _cfg("fasgd", num_clients=8), events_per_step=4, apply_mode="fused",
         queue_capacity=8, drain_policy="drain_all", admission_policy="block")
     with pytest.raises(ValueError, match="client-axis mesh"):
         run_simulation(cfg, loss, params, ds.x_train, ds.y_train, 8,
-                       eval_every=8, mesh=make_mesh_compat((1,), ("clients",)))
+                       eval_every=8, mesh=make_mesh((1,), ("clients",)))
 
 
 # ---------------------------------------------------------------------------

@@ -100,8 +100,8 @@ def test_peak_bytes_shrink_with_shards():
 def test_validate_server_mesh_rejects():
     with pytest.raises(ValueError, match="server_shards=2"):
         server_shard.validate_server_mesh(None, 2)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("server", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("server", "data"))
     with pytest.raises(ValueError, match="axis size 1"):
         server_shard.validate_server_mesh(mesh, 2)
     server_shard.validate_server_mesh(mesh, 1)   # exact size passes
@@ -151,8 +151,8 @@ def test_one_shard_bitwise_identical(mlp_setup, rule, apply_mode, per_tensor):
     cfg = _sim_cfg(rule, apply_mode, per_tensor, shards=1)
     base = _run(mlp_setup, cfg)
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("server",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("server",))
     sharded = _run(mlp_setup, cfg, mesh=mesh)
 
     assert tree_equal(base["state"].server.params,
@@ -224,14 +224,14 @@ _MULTIDEV_SCRIPT = textwrap.dedent("""
     from repro.core.rules import ServerConfig
     from repro.core.bandwidth import BandwidthConfig
     from repro.sim.fred import SimConfig, run_simulation
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models.mlp import init_mlp, nll_loss
     from repro.data.mnist import make_synth_mnist
 
     assert len(jax.devices()) == 2, jax.devices()
     params = init_mlp(jax.random.PRNGKey(0))
     ds = make_synth_mnist(n_train=256, n_valid=128)
-    mesh = make_mesh_compat((2,), ("server",))
+    mesh = make_mesh((2,), ("server",))
 
     def run(rule, shards, mesh):
         sync = server_rules.get_rule(rule).synchronous

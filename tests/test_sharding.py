@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.sharding.rules import (
     batch_spec, cache_specs, constrain, constrain_axes, leaf_param_spec,
     param_specs, set_mesh_context,
@@ -16,7 +16,7 @@ def mk_mesh(shape=(2, 2), axes=("data", "model")):
     n = len(jax.devices())
     if np.prod(shape) > n:
         pytest.skip("needs more devices")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 class FakeMesh:
