@@ -273,6 +273,7 @@ def serial_kernel_active(scfg: ServerConfig,
 # gates — B-FASGD eq. 9
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("dispatch")
 def transmit_gate(key, server: ServerState, c, eps, shape=()):
     """Bernoulli eq.-9 draw(s): r < 1/(1 + c/(v̄+ε)).
 
@@ -283,6 +284,7 @@ def transmit_gate(key, server: ServerState, c, eps, shape=()):
         server_rules.vbar(server), c, eps)
 
 
+@jax.named_scope("dispatch")
 def per_tensor_gate(key, server: ServerState, c, eps):
     """Per-leaf eq.-9 draws, one per parameter tensor, driven by that
     tensor's own v̄ moving average (§5 extension, both directions).
@@ -339,6 +341,7 @@ def merge_gated_state(old: ServerState, cand: ServerState,
     )
 
 
+@jax.named_scope("server_apply")
 def apply_gated(scfg: ServerConfig, server: ServerState, grad, push, grad_ts,
                 *, client_params=None, cached_grad=None):
     """One server application under a push decision.
@@ -374,6 +377,7 @@ def apply_gated(scfg: ServerConfig, server: ServerState, grad, push, grad_ts,
 # serial application — the paper-faithful lock order
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("server_apply")
 def serial_apply(scfg: ServerConfig, server: ServerState, grads, push,
                  grad_ts, client_params=None):
     """Apply pushed gradients one at a time in event order (lock = order).
@@ -405,6 +409,7 @@ def serial_apply(scfg: ServerConfig, server: ServerState, grads, push,
 # fused application — one masked-sum update over the whole event batch
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("server_apply")
 def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
                 client_ts, client_params=None, *, mesh=None,
                 server_axis: str = "server"):
@@ -643,6 +648,7 @@ def resolve_event_batched_loss(loss_fn, batched_loss_fn=None):
     return event_batched_losses(loss_fn)
 
 
+@jax.named_scope("dispatch")
 def dedup_events(ts):
     """Group an event batch by identical fetch timestamps.
 
@@ -747,36 +753,42 @@ def fused_apply_cotangent(scfg: ServerConfig, server: ServerState,
     n_push = jnp.sum(push.astype(jnp.int32))
     taus = server_rules.step_staleness(server.timestamp, client_ts)   # [K]
     coeffs = rule.fused_coeffs(scfg, taus)                            # [K]
+    track_stats = scfg.track_stats or rule.requires_stats
 
-    deltas = jax.tree.map(
-        lambda p, w: jax.lax.stop_gradient(p - w[None]),
-        stale_params, server.params)
-    losses, pullback = jax.vjp(lambda W: event_losses(W, deltas),
-                               server.params)
-    w_delta = (pushf * coeffs).astype(losses.dtype)
-    if scfg.track_stats or rule.requires_stats:
-        w_mean = (pushf / jnp.maximum(n_push, 1)).astype(losses.dtype)
-        # one vmapped backward for both weighted sums
-        both = jax.vmap(lambda ct: pullback(ct)[0])(
-            jnp.stack([w_delta, w_mean]))
-        delta = jax.tree.map(lambda l: l[0], both)
-        mean_g = jax.tree.map(lambda l: l[1], both)
-        stats_state = rule.update_stats(scfg, server, mean_g)
-        server = tree_where(n_push > 0, stats_state, server)
-    else:
-        delta = pullback(w_delta)[0]
-    if not rule.coeffs_are_v_independent:
-        # v_separable rules (fasgd): the per-event coefficients above carry
-        # only the scalar part (lr/τ_k); the elementwise v-factor 1/(v+ε)
-        # applies once, against the post-stats v, via the re-weighting
-        # pullback (exact — see `reweight_by_v`).
-        vfac = rule.fused_vfactor(scfg, server.v)
-        _, rw_pullback = jax.vjp(
-            lambda W: reweight_by_v(W, vfac), server.params)
-        delta = rw_pullback(delta)[0]
-    new_params = jax.tree.map(jnp.subtract, server.params, delta)
-    server = server._replace(
-        params=new_params, timestamp=server.timestamp + n_push)
+    # the clients' forward and both pullbacks: their gradients, contracted
+    with jax.named_scope("client_grad"):
+        deltas = jax.tree.map(
+            lambda p, w: jax.lax.stop_gradient(p - w[None]),
+            stale_params, server.params)
+        losses, pullback = jax.vjp(lambda W: event_losses(W, deltas),
+                                   server.params)
+        w_delta = (pushf * coeffs).astype(losses.dtype)
+        if track_stats:
+            w_mean = (pushf / jnp.maximum(n_push, 1)).astype(losses.dtype)
+            # one vmapped backward for both weighted sums
+            both = jax.vmap(lambda ct: pullback(ct)[0])(
+                jnp.stack([w_delta, w_mean]))
+            delta = jax.tree.map(lambda l: l[0], both)
+            mean_g = jax.tree.map(lambda l: l[1], both)
+        else:
+            delta = pullback(w_delta)[0]
+
+    with jax.named_scope("server_apply"):
+        if track_stats:
+            stats_state = rule.update_stats(scfg, server, mean_g)
+            server = tree_where(n_push > 0, stats_state, server)
+        if not rule.coeffs_are_v_independent:
+            # v_separable rules (fasgd): the per-event coefficients above
+            # carry only the scalar part (lr/τ_k); the elementwise v-factor
+            # 1/(v+ε) applies once, against the post-stats v, via the
+            # re-weighting pullback (exact — see `reweight_by_v`).
+            vfac = rule.fused_vfactor(scfg, server.v)
+            _, rw_pullback = jax.vjp(
+                lambda W: reweight_by_v(W, vfac), server.params)
+            delta = rw_pullback(delta)[0]
+        new_params = jax.tree.map(jnp.subtract, server.params, delta)
+        server = server._replace(
+            params=new_params, timestamp=server.timestamp + n_push)
     return server, taus, losses
 
 

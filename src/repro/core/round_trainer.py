@@ -285,6 +285,7 @@ def build_round_step(
             "gating, drop_policy='discard', use_fused_kernel=False, and an "
             "event-batched loss (batched_loss_fn or grad_fn.event_batched)")
 
+    @jax.named_scope("dispatch")
     def round_step(state: RoundState, batch, key):
         k_push, k_fetch = jax.random.split(key)
         C = tc.num_round_clients
@@ -300,7 +301,8 @@ def build_round_step(
             svc_order = jnp.argsort(svc)
 
         if not use_cotangent:
-            losses, grads = jax.vmap(grad_fn)(state.client_params, batch)
+            with jax.named_scope("client_grad"):
+                losses, grads = jax.vmap(grad_fn)(state.client_params, batch)
         else:
             grads = None        # cotangent: losses come from the vjp forward
 
@@ -428,6 +430,7 @@ def build_round_step(
             fetch_sent = jnp.sum(fetch.astype(jnp.float32)) * model_bytes
 
         # --- client-side parameter refresh ---
+        @jax.named_scope("fetch_refresh")
         def upd_leaf(cp, sp, g, p, f):
             exp = (-1,) + (1,) * (cp.ndim - 1)
             f = f.reshape(exp)

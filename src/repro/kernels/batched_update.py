@@ -115,6 +115,7 @@ def batched_scale_apply_2d(
         ),
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, LANES), params.dtype),
+        name="batched_update",
         interpret=interpret,
     )(scalars, *mask_ops, coeffs.astype(jnp.float32),
       taus.astype(jnp.float32), params, v, grads)

@@ -81,5 +81,6 @@ def fasgd_update_2d(
         ],
         out_specs=[tile, tile, tile, tile],
         out_shape=[jax.ShapeDtypeStruct((R, LANES), params.dtype), f32, f32, f32],
+        name="fasgd_update",
         interpret=interpret,
     )(scalars, params, grads, n, b, v)

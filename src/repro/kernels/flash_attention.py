@@ -149,6 +149,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     if pad_q:

@@ -136,6 +136,7 @@ def fused_event_apply_2d(
         out_specs=[tile, tile, tile, tile],
         out_shape=[jax.ShapeDtypeStruct((R, LANES), params.dtype),
                    f32, f32, f32],
+        name="fused_event_apply",
         interpret=interpret,
     )(scalars, weights.astype(jnp.float32), wmean.astype(jnp.float32),
       taus.astype(jnp.float32), params, n, b, v, grads)

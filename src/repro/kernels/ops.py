@@ -10,6 +10,7 @@ per-config and is threaded here by the engine/rule call sites.
 """
 from __future__ import annotations
 
+import math
 import os
 from typing import Any
 
@@ -59,6 +60,11 @@ def default_block_rows(num_events: int) -> int:
     return 8
 
 
+# The packing around a kernel launch (leaf to padded [rows, 128] tiles and
+# back) runs under the `apply_pack` scope, so a device trace tells its
+# copies and pads apart from the kernel's own time.
+
+@jax.named_scope("apply_pack")
 def _pad_to_tiles(x: jax.Array, block_rows: int):
     flat = x.reshape(-1)
     tile = block_rows * LANES
@@ -66,6 +72,24 @@ def _pad_to_tiles(x: jax.Array, block_rows: int):
     if pad:
         flat = jnp.pad(flat, (0, pad))
     return flat.reshape(-1, LANES), pad
+
+
+@jax.named_scope("apply_pack")
+def _pad_events(g: jax.Array, rows: int):
+    """[K, ...] event gradients as [K, rows, 128], zero-padded per event."""
+    K = g.shape[0]
+    gflat = g.reshape(K, -1)
+    pad = rows * LANES - gflat.shape[1]
+    if pad:
+        gflat = jnp.pad(gflat, ((0, 0), (0, pad)))
+    return gflat.reshape(K, -1, LANES)
+
+
+@jax.named_scope("apply_pack")
+def _unpad(a: jax.Array, shape, dtype=None):
+    """A kernel output's padded tiles back to the leaf's shape (and dtype)."""
+    out = a.reshape(-1)[:math.prod(shape)].reshape(shape)
+    return out if dtype is None else out.astype(dtype)
 
 
 def fasgd_update(params: Any, grads: Any, n: Any, b: Any, v: Any, lr, tau,
@@ -91,9 +115,8 @@ def fasgd_update(params: Any, grads: Any, n: Any, b: Any, v: Any, lr, tau,
             gamma=gamma, beta=beta, eps=eps, variant=variant,
             block_rows=rows, interpret=interpret,
         )
-        size = p.size
-        unpad = lambda a: a.reshape(-1)[:size].reshape(shape)
-        return unpad(po).astype(dtype), unpad(no), unpad(bo), unpad(vo)
+        return (_unpad(po, shape, dtype), _unpad(no, shape),
+                _unpad(bo, shape), _unpad(vo, shape))
 
     outs = jax.tree.map(one, params, grads, n, b, v)
     # outs is a pytree of 4-tuples; transpose to 4 pytrees
@@ -148,16 +171,12 @@ def batched_scale_apply(params: Any, grads: Any, v: Any, coeffs, taus,
     def one(p, g, vv, coeff, tau, mask):
         shape, dtype = p.shape, p.dtype
         (p2, _), (v2, _) = _pad_to_tiles(p, block), _pad_to_tiles(vv, block)
-        gflat = g.reshape(K, -1)
-        pad = p2.shape[0] * LANES - gflat.shape[1]
-        if pad:
-            gflat = jnp.pad(gflat, ((0, 0), (0, pad)))
-        g2 = gflat.reshape(K, -1, LANES)
+        g2 = _pad_events(g, p2.shape[0])
         rows = min(block, p2.shape[0])
         po = _bk.batched_scale_apply_2d(
             p2, g2, v2, coeff, tau, lr, masks=mask, eps=eps, mode=mode,
             block_rows=rows, interpret=interpret)
-        return po.reshape(-1)[:p.size].reshape(shape).astype(dtype)
+        return _unpad(po, shape, dtype)
 
     outs = [one(p, g, vv, c, t, m) for p, g, vv, c, t, m in zip(
         jax.tree.leaves(params), jax.tree.leaves(grads), jax.tree.leaves(v),
@@ -241,18 +260,13 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
         (p2, _), (n2, _), (b2, _), (v2, _) = (
             _pad_to_tiles(p, rows), _pad_to_tiles(nn, rows),
             _pad_to_tiles(bb, rows), _pad_to_tiles(vv, rows))
-        gflat = g.reshape(K, -1)
-        pad = p2.shape[0] * LANES - gflat.shape[1]
-        if pad:
-            gflat = jnp.pad(gflat, ((0, 0), (0, pad)))
-        g2 = gflat.reshape(K, -1, LANES)
+        g2 = _pad_events(g, p2.shape[0])
         block = min(rows, p2.shape[0])
         po, no, bo, vo = _fe.fused_event_apply_2d(
             p2, g2, n2, b2, v2, w, wm, t, lr, hp,
             block_rows=block, interpret=(path == "interpret"), **kw)
-        size = p.size
-        unpad = lambda a: a.reshape(-1)[:size].reshape(shape)
-        return unpad(po).astype(dtype), unpad(no), unpad(bo), unpad(vo)
+        return (_unpad(po, shape, dtype), _unpad(no, shape),
+                _unpad(bo, shape), _unpad(vo, shape))
 
     def one(p, g, nn, bb, vv, w, wm, t, hp, spec):
         if path == "xla":

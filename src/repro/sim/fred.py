@@ -449,10 +449,11 @@ def _build_queue_step(config: SimConfig, loss_fn, data_x, data_y, K,
     batched_losses = (
         engine.resolve_event_batched_loss(loss_fn, batched_loss_fn)
         if use_cotangent else None)
-    vgrad = jax.vmap(grad_fn)
+    vgrad = jax.named_scope("client_grad")(jax.vmap(grad_fn))
     scn = config.scenario
     scn_scales = scen.client_scales(scn, lam) if scn is not None else None
 
+    @jax.named_scope("dispatch")
     def step(state: SimState, keys):
         ks = jax.vmap(lambda k: jax.random.split(k, 4))(keys)    # [K, 4, ...]
         k_disp, k_batch = ks[:, 0], ks[:, 1]
@@ -744,6 +745,7 @@ def build_step_fn(
             config, loss_fn, data_x, data_y, K,
             batched_loss_fn=batched_loss_fn, mesh=mesh)
 
+    @jax.named_scope("dispatch")
     def event_body(state: SimState, inp):
         """One client event — the paper's protocol, verbatim.
 
@@ -762,10 +764,13 @@ def build_step_fn(
         model_bytes = tree_bytes(state.server.params)
 
         # --- client computes a stochastic gradient on its (stale) params ---
-        idx = jax.random.randint(k_batch, (config.batch_size,), 0, data_x.shape[0])
-        xb, yb = data_x[idx], data_y[idx]
-        p_c = tree_index(state.client_params, c)
-        loss, g = grad_fn(p_c, xb, yb)
+        with jax.named_scope("minibatch"):
+            idx = jax.random.randint(k_batch, (config.batch_size,), 0, data_x.shape[0])
+            xb, yb = data_x[idx], data_y[idx]
+        with jax.named_scope("stale_gather"):
+            p_c = tree_index(state.client_params, c)
+        with jax.named_scope("client_grad"):
+            loss, g = grad_fn(p_c, xb, yb)
 
         # --- push gate (B-FASGD eq. 9; per-leaf in per-tensor mode) ---
         if bw.per_tensor_push:
@@ -830,10 +835,11 @@ def build_step_fn(
             fetch_total = model_bytes
             client_leaf_ts = state.client_leaf_ts
             new_p_c = tree_where(fetch, new_server.params, p_c)
-        client_params = tree_set(state.client_params, c, new_p_c)
-        client_ts = state.client_ts.at[c].set(
-            jnp.where(fetch, new_server.timestamp, state.client_ts[c])
-        )
+        with jax.named_scope("fetch_scatter"):
+            client_params = tree_set(state.client_params, c, new_p_c)
+            client_ts = state.client_ts.at[c].set(
+                jnp.where(fetch, new_server.timestamp, state.client_ts[c])
+            )
 
         if server_rules.get_rule(scfg.rule).synchronous:
             # when a sync round completes, *every* client receives the new
@@ -934,7 +940,9 @@ def build_step_fn(
             jax.vmap(grad_fn), mesh=client_mesh,
             in_specs=(spec, spec, spec), out_specs=(spec, spec),
             check_vma=False)
+    vgrad = jax.named_scope("client_grad")(vgrad)
 
+    @jax.named_scope("dispatch")
     def step(state: SimState, keys):
         ks = jax.vmap(lambda k: jax.random.split(k, 4))(keys)    # [K, 4, ...]
         k_disp, k_batch = ks[:, 0], ks[:, 1]
@@ -959,10 +967,11 @@ def build_step_fn(
                 lambda k: jax.random.categorical(k, het_logits))(k_disp)
 
         # --- per-event minibatch draws ---
-        idx = jax.vmap(
-            lambda k: jax.random.randint(
-                k, (config.batch_size,), 0, data_x.shape[0]))(k_batch)
-        xb, yb = data_x[idx], data_y[idx]                        # [K, μ, ...]
+        with jax.named_scope("minibatch"):
+            idx = jax.vmap(
+                lambda k: jax.random.randint(
+                    k, (config.batch_size,), 0, data_x.shape[0]))(k_batch)
+            xb, yb = data_x[idx], data_y[idx]                    # [K, μ, ...]
 
         # --- event dedup: clients that fetched at the same T hold bitwise-
         # identical copies, so the stale-parameter batch is gathered through
@@ -973,7 +982,8 @@ def build_step_fn(
         dedup_key = (state.client_leaf_ts[cs] if bw.per_tensor_fetch
                      else state.client_ts[cs])
         rep, _, _ = engine.dedup_events(dedup_key)
-        p_e = tree_index(state.client_params, cs[rep])           # [K, ...]
+        with jax.named_scope("stale_gather"):
+            p_e = tree_index(state.client_params, cs[rep])       # [K, ...]
 
         # --- push gates (pre-window server state, like the serial path) ---
         if bw.per_tensor_push:
@@ -1034,39 +1044,40 @@ def build_step_fn(
         # Every fetch delivers the same canonical parameters, so duplicate
         # clients in the batch all write identical rows — the scatters are
         # deterministic and touch K rows, never the full λ fleet.
-        if bw.per_tensor_fetch:
-            fmask = jax.vmap(lambda k: engine.per_tensor_gate(
-                k, new_server, bw.c_fetch, bw.eps)[0])(k_fetch)  # leaves [K]
-            fetch = jnp.stack(jax.tree.leaves(fmask)).all(axis=0)  # [K]
-            fetch_sent = masked_bytes(fmask, new_server.params)
+        with jax.named_scope("fetch_scatter"):
+            if bw.per_tensor_fetch:
+                fmask = jax.vmap(lambda k: engine.per_tensor_gate(
+                    k, new_server, bw.c_fetch, bw.eps)[0])(k_fetch)  # leaves [K]
+                fetch = jnp.stack(jax.tree.leaves(fmask)).all(axis=0)  # [K]
+                fetch_sent = masked_bytes(fmask, new_server.params)
 
-            def fetch_leaf(m, cp, sp):
-                i = jnp.where(m, cs, lam)            # dropped when ¬fetched
-                return cp.at[i].set(
-                    jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop")
-            client_params = jax.tree.map(
-                fetch_leaf, fmask, state.client_params, new_server.params)
-            leaf_cols = []
-            for i, m in enumerate(jax.tree.leaves(fmask)):
-                rows = jnp.where(m, cs, lam)
-                leaf_cols.append(
-                    state.client_leaf_ts[:, i].at[rows].set(
-                        jnp.broadcast_to(new_server.timestamp, (K,)),
-                        mode="drop"))
-            client_leaf_ts = jnp.stack(leaf_cols, axis=1)
-        else:
-            fetch = engine.transmit_gate(
-                k_fetch[0], new_server, bw.c_fetch, bw.eps, shape=(K,))
-            fetch_sent = jnp.sum(fetch.astype(jnp.float32)) * model_bytes
-            idx = jnp.where(fetch, cs, lam)            # dropped when ¬fetch
-            client_params = jax.tree.map(
-                lambda cp, sp: cp.at[idx].set(
-                    jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop"),
-                state.client_params, new_server.params)
-            client_leaf_ts = state.client_leaf_ts
-        fetch_idx = jnp.where(fetch, cs, lam)
-        client_ts = state.client_ts.at[fetch_idx].set(
-            jnp.broadcast_to(new_server.timestamp, (K,)), mode="drop")
+                def fetch_leaf(m, cp, sp):
+                    i = jnp.where(m, cs, lam)            # dropped when ¬fetched
+                    return cp.at[i].set(
+                        jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop")
+                client_params = jax.tree.map(
+                    fetch_leaf, fmask, state.client_params, new_server.params)
+                leaf_cols = []
+                for i, m in enumerate(jax.tree.leaves(fmask)):
+                    rows = jnp.where(m, cs, lam)
+                    leaf_cols.append(
+                        state.client_leaf_ts[:, i].at[rows].set(
+                            jnp.broadcast_to(new_server.timestamp, (K,)),
+                            mode="drop"))
+                client_leaf_ts = jnp.stack(leaf_cols, axis=1)
+            else:
+                fetch = engine.transmit_gate(
+                    k_fetch[0], new_server, bw.c_fetch, bw.eps, shape=(K,))
+                fetch_sent = jnp.sum(fetch.astype(jnp.float32)) * model_bytes
+                idx = jnp.where(fetch, cs, lam)            # dropped when ¬fetch
+                client_params = jax.tree.map(
+                    lambda cp, sp: cp.at[idx].set(
+                        jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop"),
+                    state.client_params, new_server.params)
+                client_leaf_ts = state.client_leaf_ts
+            fetch_idx = jnp.where(fetch, cs, lam)
+            client_ts = state.client_ts.at[fetch_idx].set(
+                jnp.broadcast_to(new_server.timestamp, (K,)), mode="drop")
 
         counters = engine.count_events(
             state.counters, push_event, fetch,

@@ -10,6 +10,7 @@ the xdist worker given this file loads the TPU compiler.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +74,12 @@ def _kernel_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _launches(text, kernel):
+    """The compiled program's custom calls named after `kernel` (the
+    `pallas_call`'s `name=`), as a device trace shows them."""
+    return re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*custom-call\(", text)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("leaf", [MLP_LEAF, LM_LEAF], ids=["mlp", "lm"])
@@ -90,7 +97,9 @@ def test_fused_event_apply_compiles(one_chip, K, leaf, dtype):
             {"w": p}, {"w": g}, {"w": n}, {"w": b}, {"w": v}, w, wm, t,
             jnp.bool_(True), lr=0.005, interpret=False)
 
-    assert "tpu_custom_call" in _kernel_text(apply, *args)
+    text = _kernel_text(apply, *args)
+    assert "tpu_custom_call" in text
+    assert len(_launches(text, "fused_event_apply")) == 1
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -103,7 +112,9 @@ def test_fasgd_update_compiles(one_chip, dtype):
         return ops.fasgd_update({"w": p}, {"w": g}, {"w": n}, {"w": b},
                                 {"w": v}, 0.005, 3.0, interpret=False)
 
-    assert "tpu_custom_call" in _kernel_text(update, *[leaf] * 5)
+    text = _kernel_text(update, *[leaf] * 5)
+    assert "tpu_custom_call" in text
+    assert len(_launches(text, "fasgd_update")) == 1
 
 
 def _fred_step_text(cfg, mesh, place, sharding):
@@ -126,7 +137,10 @@ def test_fred_fused_step_holds_kernel(one_chip):
                          kernel_interpret=False)
     place = lambda tree: jax.tree.map(
         lambda x: _abstract(x.shape, x.dtype, one_chip), tree)
-    assert "tpu_custom_call" in _fred_step_text(cfg, None, place, one_chip)
+    text = _fred_step_text(cfg, None, place, one_chip)
+    assert "tpu_custom_call" in text
+    # one launch per leaf of the MLP
+    assert len(_launches(text, "fused_event_apply")) == 4
 
 
 @pytest.mark.parametrize("axis", ["server", "clients"])
