@@ -151,8 +151,8 @@ class TrainerConfig:
     fused_mode: str = "auto"
     # one-kernel apply tuning (kernels/fused_event_apply.py): force / forbid
     # Pallas interpret mode (None = auto: env REPRO_KERNEL_INTERPRET, then
-    # platform), and override the block_rows tile height (0 = K-dependent
-    # table in kernels.ops.default_block_rows).
+    # platform), and override the row block (0 = derived from the leaf's
+    # shape, dtypes and K against a VMEM budget: kernels.ops.apply_blocks).
     kernel_interpret: Optional[bool] = None
     kernel_block_rows: int = 0
     # --- bounded server ingress queue (core/queue.py) ---

@@ -103,7 +103,7 @@ class ServerConfig:
     # (False), or auto (None — native on TPU, interpret / XLA-streaming
     # fallback elsewhere; overridable via REPRO_KERNEL_INTERPRET).
     kernel_interpret: Optional[bool] = None
-    kernel_block_rows: int = 0  # 0 → the per-K tuned table (ops.default_block_rows)
+    kernel_block_rows: int = 0  # 0 → derived from a VMEM budget (ops.apply_blocks)
 
     def __post_init__(self):
         get_rule(self.rule)     # raises KeyError for unregistered names

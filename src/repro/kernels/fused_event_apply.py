@@ -24,8 +24,12 @@ versus ≈ 6K + 14 for the split schedule (stats contraction K+1, moving
 averages ~10, broadcast delta 5K+3).  See `benchmarks/kernels.py`
 (``hbm_model_one_kernel``) — the bound is also *measured* there.
 
-Layout follows `batched_update.py`: (rows, 128) lane-aligned tiles, gradients
-stacked [K, rows, 128], per-event scalars in SMEM.  ``interpret=True``
+Layout: each leaf in its own layout, viewed as (R, C) or, where its leading
+dims cannot merge into the rows without moving data, (L, R, C) with L a
+squeezed grid axis; gradients stacked [K, ...] over the same view, per-event
+scalars in SMEM.  The grid tiles R and C with (block_rows, block_cols)
+blocks; a ragged last block reads past the edge and its out-of-bounds writes
+are dropped, which an elementwise kernel allows.  ``interpret=True``
 executes the identical kernel on CPU for CI correctness
 (`ops.fused_event_apply` additionally offers an XLA streaming fallback with
 the same semantics for off-TPU *timing* — see `ref.fused_event_apply_ref`).
@@ -87,11 +91,11 @@ def _kernel(scal_ref, w_ref, wm_ref, tau_ref,
 
 
 def fused_event_apply_2d(
-    params: jax.Array,   # (R, 128) — any float dtype
-    grads: jax.Array,    # (K, R, 128)
-    n: jax.Array,        # (R, 128) float32
-    b: jax.Array,        # (R, 128) float32
-    v: jax.Array,        # (R, 128) float32
+    params: jax.Array,   # (R, C) or (L, R, C) — any float dtype
+    grads: jax.Array,    # (K, *params.shape)
+    n: jax.Array,        # params.shape, float32
+    b: jax.Array,        # params.shape, float32
+    v: jax.Array,        # params.shape, float32
     weights: jax.Array,  # (K,) float32 — mask×coeff ('coeff') or mask ('fasgd')
     wmean: jax.Array,    # (K,) float32 — m_k / max(n_push, 1)
     taus: jax.Array,     # (K,) float32 — this leaf's per-event staleness
@@ -105,38 +109,60 @@ def fused_event_apply_2d(
     mode: str = "fasgd",
     track_stats: bool = True,
     block_rows: int = 256,
+    block_cols: int | None = None,
     interpret: bool = False,
 ):
-    """One fused K-event server apply over tile-aligned buffers.
+    """One fused K-event server apply over a leaf's (R, C) or (L, R, C) view.
 
-    Returns ``(params', n', b', v')``; with ``track_stats=False`` the
-    statistics pass through unchanged (the caller already advanced them, or
-    tracking is off).  Semantically equal to `ref.fused_event_apply_ref`.
+    ``block_rows`` must be R or a multiple of the sublane tile, and
+    ``block_cols`` (None: C) C or a multiple of 128.  Returns
+    ``(params', n', b', v')``; with ``track_stats=False`` the statistics
+    pass through unchanged (the caller already advanced them, or tracking
+    is off).  Semantically equal to `ref.fused_event_apply_ref`.
     """
     assert mode in ("coeff", "fasgd"), mode
-    K, R, lanes = grads.shape
-    assert lanes == LANES and params.shape == (R, LANES), (grads.shape,
-                                                           params.shape)
-    assert R % block_rows == 0, (R, block_rows)
-    grid = (R // block_rows,)
-    tile = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    gtile = pl.BlockSpec((K, block_rows, LANES), lambda i: (0, i, 0))
+    K = grads.shape[0]
+    assert params.ndim in (2, 3) and grads.shape == (K,) + params.shape, (
+        grads.shape, params.shape)
+    *lead, R, C = params.shape
+    br, bc = min(block_rows, R), min(block_cols or C, C)
+    grid = (*lead, pl.cdiv(R, br), pl.cdiv(C, bc))
+    if lead:
+        tile = pl.BlockSpec((pl.squeezed, br, bc), lambda l, i, j: (l, i, j))
+        gtile = pl.BlockSpec((K, pl.squeezed, br, bc),
+                             lambda l, i, j: (0, l, i, j))
+    else:
+        tile = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+        gtile = pl.BlockSpec((K, br, bc), lambda i, j: (0, i, j))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     scalars = jnp.stack([jnp.asarray(lr, jnp.float32),
                          jnp.asarray(has_push, jnp.float32)])
     kern = functools.partial(
         _kernel, num_events=K, mode=mode, gamma=gamma, beta=beta, eps=eps,
         variant=variant, track_stats=track_stats)
-    f32 = jax.ShapeDtypeStruct((R, LANES), jnp.float32)
+    f32 = jax.ShapeDtypeStruct(params.shape, jnp.float32)
+    # What a launch costs, for XLA's scheduling and its placement of the
+    # buffers around the kernel: each element reads θ, n, b, v and its K
+    # gradients and writes θ', n', b', v' once; per event 2 flops of the
+    # mean gradient and 2 ('coeff') or 6 (eq. 7's scale) of the delta, and
+    # the statistics step's ~17 flops and one square root.
+    stats = int(track_stats)
+    per_event = 2 * stats + (6 if mode == "fasgd" else 2)
+    cost = pl.CostEstimate(
+        flops=params.size * (per_event * K + 17 * stats + 1),
+        transcendentals=params.size * stats,
+        bytes_accessed=params.size * (K * grads.dtype.itemsize
+                                      + 2 * params.dtype.itemsize + 6 * 4))
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[smem, smem, smem, smem,       # (lr, has_push), w, wmean, τ
                   tile, tile, tile, tile, gtile],
         out_specs=[tile, tile, tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((R, LANES), params.dtype),
+        out_shape=[jax.ShapeDtypeStruct(params.shape, params.dtype),
                    f32, f32, f32],
         name="fused_event_apply",
+        cost_estimate=cost,
         interpret=interpret,
     )(scalars, weights.astype(jnp.float32), wmean.astype(jnp.float32),
       taus.astype(jnp.float32), params, n, b, v, grads)

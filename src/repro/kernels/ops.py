@@ -45,24 +45,77 @@ def _auto_interpret(interpret):
     return interpret
 
 
-# `fused_event_apply` row-block tuning table, keyed by event count K: the
-# [K, rows, 128] gradient block must fit VMEM alongside the five leaf tiles,
-# so deeper event batches take narrower row blocks.  Measured by the
-# `block_rows` sweep in benchmarks/kernels.py; override per-config with
-# ServerConfig.kernel_block_rows.
-_BLOCK_ROWS_TABLE = ((8, 512), (32, 256), (128, 64), (512, 16))
+# VMEM one grid step of `fused_event_apply` may fill with its blocks: every
+# operand's block double-buffered by the pipeline, and the body's float32
+# temporaries.  Under the 16 MiB a TPU kernel's scoped VMEM holds by default.
+APPLY_VMEM_BUDGET = 12 << 20
+_F32_TEMPS = 8          # gbar, n1, b1, v1, std, scale, the event's g, acc
 
 
-def default_block_rows(num_events: int) -> int:
-    for k, rows in _BLOCK_ROWS_TABLE:
-        if num_events <= k:
-            return rows
-    return 8
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def sublanes(dtype) -> int:
+    """Rows of a dtype's native (sublanes, 128) tile: 8 f32, 16 bf16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def apply_blocks(R: int, C: int, K: int, param_dtype, grad_dtype,
+                 block_rows: int = 0):
+    """(block_rows, block_cols) of `fused_event_apply` on an (R, C) view.
+
+    Derived from one VMEM budget: a block of br × bc elements holds, per
+    element, θ in and out, n/b/v in and out (float32) and the K gradients,
+    each double-buffered, plus the body's float32 temporaries, all counted
+    at the native tile's padding.  The lane block is C itself when a
+    sublane-tall full-width block fits; otherwise a multiple of 128, the
+    widest that fits.  The row block is R or a multiple of the sublane tile.
+    Both are then evened out over their grid axis, so that a ragged last
+    block wastes little.  `block_rows` > 0 overrides the row block.
+    """
+    p_item = jnp.dtype(param_dtype).itemsize
+    g_item = jnp.dtype(grad_dtype).itemsize
+    sub = max(sublanes(d) for d in (param_dtype, grad_dtype, jnp.float32))
+    per_elem = 2 * (K * g_item + 2 * p_item + 6 * 4) + 4 * _F32_TEMPS
+    cap = max(APPLY_VMEM_BUDGET // per_elem, sub * LANES)  # a block's elements
+    if sub * _round_up(C, LANES) <= cap:
+        bc = C
+    else:
+        lane_tiles = -(-C // LANES)
+        n = -(-lane_tiles // (cap // (sub * LANES)))
+        bc = -(-lane_tiles // n) * LANES
+    if block_rows:
+        br = block_rows
+    else:
+        br = max(sub, cap // _round_up(bc, LANES) // sub * sub)
+        if br < R:
+            br = _round_up(-(-R // -(-R // br)), sub)
+    if br >= R:
+        return R, bc
+    return max(sub, br // sub * sub), bc
+
+
+def _kernel_view(shape, dtypes):
+    """The leaf's shape as the kernel reads it, by bitcasts alone: (1, C)
+    for a vector, (R, C) for a matrix, and for a stacked leaf its leading
+    dims merged into the rows where its second-minor dim is a multiple of
+    every operand's sublane tile, else into one squeezed axis: (L, R, C)."""
+    if len(shape) < 2:
+        return (1, math.prod(shape))
+    *lead, R, C = shape
+    L = math.prod(lead)
+    if L == 1:
+        return (R, C)
+    if R % max(sublanes(d) for d in dtypes) == 0:
+        return (L * R, C)
+    return (L, R, C)
 
 
 # The packing around a kernel launch (leaf to padded [rows, 128] tiles and
-# back) runs under the `apply_pack` scope, so a device trace tells its
-# copies and pads apart from the kernel's own time.
+# back for `fasgd_update` and `batched_scale_apply`; the views of
+# `fused_event_apply`) runs under the `apply_pack` scope, so a device trace
+# tells what it costs apart from the kernel's own time.
 
 @jax.named_scope("apply_pack")
 def _pad_to_tiles(x: jax.Array, block_rows: int):
@@ -223,8 +276,10 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     the whole tree or a per-leaf pytree mirroring `params` (per-tensor
     gating / per-tensor staleness).  `n`/`b`/`v` must be float32 (the
     engine casts); returns (params', n', b', v') with statistics in
-    float32.  `block_rows=0` uses the per-K tuned table
-    (`default_block_rows`); `interpret` dispatches per `_fused_event_path`.
+    float32.  The kernel reads and writes each leaf in its own layout
+    (`_kernel_view`), in blocks that `apply_blocks` derives from the leaf's
+    shape, its dtypes and K; `block_rows` > 0 overrides the row block.
+    `interpret` dispatches per `_fused_event_path`.
 
     The compiler cannot partition a Mosaic kernel, so under a device `mesh`
     each leaf's launch runs inside `jax.shard_map`: every device applies
@@ -236,11 +291,6 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     """
     path = _fused_event_path(interpret)
     K = jax.tree.leaves(grads)[0].shape[0]
-    rows = block_rows or default_block_rows(K)
-    # Bound the [K, rows, 128] gradient block to ~4 MB of VMEM.
-    rows_budget = max(8, (4 << 20) // (LANES * 4 * max(K, 1)))
-    rows = min(rows, 1 << (rows_budget.bit_length() - 1))
-
     params_def = jax.tree.structure(params)
 
     def per_leaf(x):
@@ -256,17 +306,24 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
               track_stats=track_stats)
 
     def launch(p, g, nn, bb, vv, w, wm, t, hp):
-        shape, dtype = p.shape, p.dtype
-        (p2, _), (n2, _), (b2, _), (v2, _) = (
-            _pad_to_tiles(p, rows), _pad_to_tiles(nn, rows),
-            _pad_to_tiles(bb, rows), _pad_to_tiles(vv, rows))
-        g2 = _pad_events(g, p2.shape[0])
-        block = min(rows, p2.shape[0])
-        po, no, bo, vo = _fe.fused_event_apply_2d(
-            p2, g2, n2, b2, v2, w, wm, t, lr, hp,
-            block_rows=block, interpret=(path == "interpret"), **kw)
-        return (_unpad(po, shape, dtype), _unpad(no, shape),
-                _unpad(bo, shape), _unpad(vo, shape))
+        shape = p.shape
+        view = _kernel_view(shape, (p.dtype, g.dtype, jnp.float32))
+        # A leaf narrower than a lane tile is applied transposed, its longer
+        # dim on the lanes: the kernel is elementwise, and XLA folds the
+        # transpose into the layout in which the backward writes the
+        # gradients, where 128 lanes would hold a handful of values.
+        flip = view[-1] < LANES and view[-2] > view[-1]
+        tr = (lambda x: jnp.swapaxes(x, -1, -2)) if flip else (lambda x: x)
+        with jax.named_scope("apply_pack"):
+            p2, n2, b2, v2 = (tr(x.reshape(view)) for x in (p, nn, bb, vv))
+            g2 = tr(g.reshape((K,) + view))
+        br, bc = apply_blocks(*p2.shape[-2:], K, p.dtype, g.dtype,
+                              block_rows)
+        outs = _fe.fused_event_apply_2d(
+            p2, g2, n2, b2, v2, w, wm, t, lr, hp, block_rows=br,
+            block_cols=bc, interpret=(path == "interpret"), **kw)
+        with jax.named_scope("apply_pack"):
+            return tuple(tr(o).reshape(shape) for o in outs)
 
     def one(p, g, nn, bb, vv, w, wm, t, hp, spec):
         if path == "xla":

@@ -242,8 +242,8 @@ def main():
                     help="Pallas interpret-mode toggle for the kernel path "
                          "(auto = env REPRO_KERNEL_INTERPRET, then platform)")
     ap.add_argument("--kernel-block-rows", type=int, default=0,
-                    help="tile height for the one-kernel apply "
-                         "(0 = K-dependent tuning table)")
+                    help="row block of the one-kernel apply "
+                         "(0 = derived from a VMEM budget)")
     ap.add_argument("--server-shards", type=int, default=1,
                     help="partition the server state (W + eq. 4-6 stats) "
                          "across S devices along a 'server' mesh axis "

@@ -5,9 +5,10 @@ XLA keeps the name stack as each instruction's `op_name` metadata, and the
 profiler copies it into the op's `tf_op` stat: these names are what the
 benchmark's reduction by scope (`bench/scopes.py`) reads.  Each case
 compiles one program at smoke size on the CPU, the Pallas apply in
-interpret mode so that its packing is lowered too, and looks for every
+interpret mode so that its views of the leaves are lowered too, and looks for every
 scope the program's path must carry.
 """
+import math
 import re
 
 import jax
@@ -34,7 +35,45 @@ def _scopes(op_names):
             for w in re.findall(r"[A-Za-z_][A-Za-z0-9_]*", n)}
 
 
+def _pack_ops(compiled_text):
+    """(opcode, elements) of every compiled op under `apply_pack`."""
+    pat = (r'^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(.*'
+           r'op_name="[^"]*apply_pack')
+    return [(op, math.prod(int(d) for d in dims.split(",") if d))
+            for dims, op in re.findall(pat, compiled_text, re.M)]
+
+
+def _lowered_pack_ops(lowered_text):
+    """(op, location) of every op the lowering put under `apply_pack`."""
+    locs = dict(re.findall(r"^#(loc\d+) = (.*)$", lowered_text, re.M))
+    ops = re.findall(r'^\s*%[^=]+= "?([\w.]+)"?[ (].*loc\(#(loc\d+)\)\s*$',
+                     lowered_text, re.M)
+    return [(op, locs[loc]) for op, loc in ops
+            if "apply_pack" in locs.get(loc, "")]
+
+
+def _assert_pack_is_views(lowered, params):
+    """The kernel reads each leaf in its own layout: what `apply_pack`
+    holds is views of the leaves (reshapes in the lowering, and transposes
+    of a leaf narrower than a lane tile), and what is left of it after
+    compiling moves at most one leaf's state (bitcasts, layout copies,
+    transposes): never a pad, a slice or anything of the [K, ...] batch's
+    size."""
+    ops = _lowered_pack_ops(lowered.as_text(debug_info=True))
+    assert {op for op, _ in ops} <= {"stablehlo.reshape",
+                                     "stablehlo.transpose"}, ops
+    largest = max(x.size for x in jax.tree.leaves(params))
+    left = _pack_ops(lowered.compile().as_text())
+    assert all(op not in ("pad", "slice") and size <= largest
+               for op, size in left), left
+
+
 def _fred_window(rule, fused_mode, use_fused_kernel):
+    return _fred_lowered(rule, fused_mode, use_fused_kernel).compile(
+    ).as_text()
+
+
+def _fred_lowered(rule, fused_mode, use_fused_kernel):
     params = init_mlp(jax.random.PRNGKey(0), SIZES)
     x = jax.random.normal(jax.random.PRNGKey(1), (32, SIZES[0]))
     y = jax.random.randint(jax.random.PRNGKey(2), (32,), 0, SIZES[-1])
@@ -47,8 +86,7 @@ def _fred_window(rule, fused_mode, use_fused_kernel):
     step = build_step_fn(cfg, nll_loss, x, y)
     state = init_sim(cfg, params)
     keys = jax.random.split(jax.random.PRNGKey(3), (1, K))
-    return jax.jit(lambda s, k: jax.lax.scan(step, s, k)).lower(
-        state, keys).compile().as_text()
+    return jax.jit(lambda s, k: jax.lax.scan(step, s, k)).lower(state, keys)
 
 
 FRED = {"dispatch", "minibatch", "stale_gather", "client_grad",
@@ -56,7 +94,7 @@ FRED = {"dispatch", "minibatch", "stale_gather", "client_grad",
 
 
 @pytest.mark.parametrize("rule,fused_mode,kernel,scopes", [
-    ("fasgd", "materialized", True, FRED | {"apply_pack"}),
+    ("fasgd", "materialized", True, FRED),
     ("asgd", "cotangent", False, FRED),
 ], ids=["fused_fasgd", "cotangent_asgd"])
 def test_fred_window_scopes(rule, fused_mode, kernel, scopes):
@@ -67,14 +105,22 @@ def test_fred_window_scopes(rule, fused_mode, kernel, scopes):
     grads = [n for n in names if "client_grad" in n]
     assert any("transpose(" in n for n in grads)
     assert any("transpose(" not in n for n in grads)
+    if kernel:
+        _assert_pack_is_views(_fred_lowered(rule, fused_mode, kernel),
+                              init_mlp(jax.random.PRNGKey(0), SIZES))
 
 
 def test_fred_kernel_apply_packs_under_server_apply():
-    """The pads around the kernel nest inside the apply, so the innermost
-    scope tells them apart from the kernel's own time."""
-    names = _op_names(_fred_window("fasgd", "materialized", True))
-    packs = [n for n in names if "apply_pack" in n]
-    assert packs and all("server_apply" in n for n in packs)
+    """The kernel's views of the leaves nest inside the apply, so the
+    innermost scope tells whatever they cost apart from the kernel's own
+    time, and the kernel itself runs under the apply."""
+    lowered = _fred_lowered("fasgd", "materialized", True)
+    packs = _lowered_pack_ops(lowered.as_text(debug_info=True))
+    assert packs and all("server_apply/apply_pack" in p for _, p in packs)
+    names = _op_names(lowered.compile().as_text())
+    assert all("server_apply" in n for n in names if "apply_pack" in n)
+    kernel = [n for n in names if "fused_event_apply" in n]
+    assert kernel and all("server_apply" in n for n in kernel)
 
 
 def test_round_step_scopes():
@@ -90,10 +136,10 @@ def test_round_step_scopes():
 
     step = build_round_step(tc, grad_fn, apply_mode="fused")
     state = init_round_state(tc, params)
-    text = jax.jit(step).lower(
-        state, (x, y), jax.random.PRNGKey(4)).compile().as_text()
-    names = _op_names(text)
-    missing = ({"dispatch", "client_grad", "server_apply", "apply_pack",
-                "fetch_refresh"} - _scopes(names))
+    lowered = jax.jit(step).lower(state, (x, y), jax.random.PRNGKey(4))
+    names = _op_names(lowered.compile().as_text())
+    missing = ({"dispatch", "client_grad", "server_apply", "fetch_refresh"}
+               - _scopes(names))
     assert not missing, f"scopes missing from the round step: {missing}"
     assert any(n.startswith("jit(round_step)/dispatch/") for n in names)
+    _assert_pack_is_views(lowered, params)

@@ -27,7 +27,8 @@ from repro.core import rules as server_rules
 from repro.core.bandwidth import BandwidthConfig
 from repro.core.rules import ServerConfig
 from repro.kernels.fused_event_apply import LANES, fused_event_apply_2d
-from repro.kernels.ops import default_block_rows, fused_event_apply
+from repro.kernels.ops import (APPLY_VMEM_BUDGET, apply_blocks,
+                               fused_event_apply, sublanes)
 from repro.kernels.ref import fused_event_apply_ref
 from repro.sim.fred import SimConfig, run_simulation
 
@@ -85,36 +86,95 @@ def test_kernel_2d_track_stats_toggle(track_stats):
     assert not np.allclose(np.asarray(po), np.asarray(p))
 
 
-@pytest.mark.parametrize("shape", [(7,), (130,), (3, 5, 7), (256, 128)])
-def test_ops_wrapper_ragged_shapes(shape):
-    """ops.fused_event_apply pads leaves to (R, 128) tiles; the interpret
-    and streaming-XLA dispatch paths agree with the oracle."""
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.mark.parametrize("shape,dtype,block_rows", [
+    pytest.param((7,), F32, 0, id="shape0"),
+    pytest.param((130,), F32, 0, id="shape1"),
+    pytest.param((3, 5, 7), F32, 0, id="shape2"),
+    pytest.param((256, 128), F32, 0, id="shape3"),
+    # the MLP's leaves, as the FRED cells hold them
+    pytest.param((784, 200), F32, 0, id="mlp_w1"),
+    pytest.param((200,), F32, 0, id="mlp_b1"),
+    pytest.param((200, 10), F32, 0, id="mlp_w2"),
+    pytest.param((10,), F32, 0, id="mlp_b2"),
+    # layer-stacked: 32 rows merge into (128, 136); 5 rows cannot, and
+    # the 4 layers take a squeezed grid axis
+    pytest.param((4, 32, 136), F32, 0, id="stacked"),
+    pytest.param((4, 5, 136), F32, 0, id="stacked_squeezed"),
+    # 100 rows in blocks of 16: a ragged last row block
+    pytest.param((100, 200), F32, 16, id="ragged_rows"),
+    # wide enough that the lane block is narrower than C, ragged at the end
+    pytest.param((16, 20000), F32, 0, id="lane_blocks"),
+    # bf16 params and gradients, float32 statistics
+    pytest.param((48, 136), BF16, 0, id="bf16_params"),
+])
+def test_ops_wrapper_ragged_shapes(shape, dtype, block_rows):
+    """ops.fused_event_apply reads each leaf in its own layout, in blocks
+    that need not divide it; the interpret and streaming-XLA dispatch paths
+    agree with the oracle."""
     K = 4
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
-    p = jax.random.normal(ks[0], shape)
-    g = 0.1 * jax.random.normal(ks[1], (K,) + shape)
+    p = jax.random.normal(ks[0], shape).astype(dtype)
+    g = (0.1 * jax.random.normal(ks[1], (K,) + shape)).astype(dtype)
     n = jnp.abs(0.01 * jax.random.normal(ks[2], shape))
     b = jnp.zeros(shape)
     v = 1.0 + 0.1 * jnp.abs(jax.random.normal(ks[3], shape))
     w = jnp.array([0.5, 0.0, 1.0, 0.25])
     wm = jnp.array([0.25] * K)
     taus = jnp.array([1.0, 2.0, 3.0, 4.0])
-    tree = lambda x: {"a": x, "b": x * 2.0}
+    tree = lambda x: {"a": x, "b": x * 2}
     ref = fused_event_apply_ref(p, g, n, b, v, w, wm, taus, 0.01, 1.0)
+    # one bf16 unit in the last place, where the params are bf16
+    rtol = [1e-5 if dtype == F32 else 8e-3] + [1e-5] * 3
     for interp in (True, None):   # None → CPU auto → streaming XLA path
         out = fused_event_apply(
             tree(p), tree(g), tree(n), tree(b), tree(v), tree(w), tree(wm),
-            tree(taus), tree(jnp.asarray(1.0)), lr=0.01, interpret=interp)
-        for o, r in zip(out, ref):
-            np.testing.assert_allclose(np.asarray(o["a"]), np.asarray(r),
-                                       rtol=1e-5, atol=1e-6)
-        assert out[0]["a"].shape == shape
+            tree(taus), tree(jnp.asarray(1.0)), lr=0.01, interpret=interp,
+            block_rows=block_rows)
+        for o, r, tol in zip(out, ref, rtol):
+            np.testing.assert_allclose(
+                np.asarray(o["a"], np.float32), np.asarray(r, np.float32),
+                rtol=tol, atol=1e-6)
+        assert out[0]["a"].shape == shape and out[0]["a"].dtype == dtype
 
 
-def test_default_block_rows_table():
-    """Tile height shrinks as the event batch (VMEM gradient slab) grows."""
-    assert default_block_rows(1) >= default_block_rows(64) \
-        >= default_block_rows(1024) >= 8
+def _block_vmem(br, bc, K, p_dtype, g_dtype):
+    """VMEM of one grid step: each operand's block at its native tile's
+    padding, double-buffered, and eight float32 temporaries."""
+    def tile(rows, dtype):
+        return (-(-rows // sublanes(dtype)) * sublanes(dtype)
+                * -(-bc // LANES) * LANES * jnp.dtype(dtype).itemsize)
+    blocks = (K * tile(br, g_dtype) + 2 * tile(br, p_dtype)
+              + 6 * tile(br, jnp.float32))
+    return 2 * blocks + 8 * tile(br, jnp.float32)
+
+
+@pytest.mark.parametrize("R,C,K,dtype", [
+    (784, 200, 128, F32), (1, 200, 128, F32), (200, 10, 128, F32),
+    (1, 10, 128, F32),                                 # the MLP at K=128
+    (2048, 50304, 4, BF16), (50304, 2048, 4, BF16),    # the LM's vocab leaves
+    (8192, 8512, 4, BF16), (4, 4352, 4, BF16),         # in_proj, conv_w
+    (16, 20000, 4, F32), (100, 200, 4, F32),
+], ids=["w1", "b1", "w2", "b2", "unembed", "embed", "in_proj", "conv_w",
+        "wide", "small"])
+def test_apply_blocks_fit_vmem_budget(R, C, K, dtype):
+    """The blocks come from one VMEM budget: rows R or a multiple of the
+    sublane tile, lanes C or a multiple of 128, within the budget, and the
+    full width wherever a sublane-tall full-width block fits."""
+    sub = max(sublanes(dtype), sublanes(jnp.float32))
+    br, bc = apply_blocks(R, C, K, dtype, dtype)
+    assert br == R or (br % sub == 0 and br < R)
+    assert bc == C or (bc % LANES == 0 and bc < C)
+    assert _block_vmem(br, bc, K, dtype, dtype) <= APPLY_VMEM_BUDGET
+    assert (bc == C) == (_block_vmem(sub, C, K, dtype, dtype)
+                         <= APPLY_VMEM_BUDGET)
+    # an override sets the row block, rounded to the sublane tile
+    assert apply_blocks(R, C, K, dtype, dtype, block_rows=R + 1)[0] == R
+    if R > 2 * sub:
+        assert apply_blocks(R, C, K, dtype, dtype,
+                            block_rows=sub + 1)[0] == sub
 
 
 def _cfg(rule, **kw):

@@ -9,6 +9,7 @@ times.  The topology is described inside a fixture, never at import: only
 the xdist worker given this file loads the TPU compiler.
 """
 import importlib.util
+import math
 import os
 import re
 
@@ -27,7 +28,9 @@ from repro.sim.fred import build_step_fn, init_sim
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MLP_LEAF = (784, 200)          # the paper's first layer
+MLP_W2 = (200, 10)             # its second, narrower than a lane tile
 LM_LEAF = (2048, 5632)         # tinyllama-1.1b's d_model x d_ff
+LM_HEAD = (2048, 50304)        # the Mamba2 cell's unembedding
 
 
 def _chip_smoke():
@@ -74,18 +77,48 @@ def _kernel_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _instructions(text, opcode):
+    """{name: (result type, line)} of the compiled program's `opcode`
+    instructions, in every computation."""
+    pat = rf"^\s*(?:ROOT )?%(\S+) = (\S+) {re.escape(opcode)}\("
+    return {m.group(1): (m.group(2), m.group(0))
+            for m in re.finditer(pat + r".*$", text, re.M)}
+
+
+def _producer(text, name):
+    """The instruction that defines `%name`, through any bitcasts:
+    (opcode, result type, op_name)."""
+    while True:
+        m = re.search(rf"^\s*(?:ROOT )?%{re.escape(name)} = (\S+) "
+                      rf"([\w-]+)\((%[^,)\s]+)?(.*)$", text, re.M)
+        opcode, operand = m.group(2), m.group(3)
+        if opcode != "bitcast":
+            op_name = re.search(r'op_name="([^"]*)"', m.group(4))
+            return opcode, m.group(1), op_name and op_name.group(1)
+        name = operand[1:]
+
+
 def _launches(text, kernel):
     """The compiled program's custom calls named after `kernel` (the
     `pallas_call`'s `name=`), as a device trace shows them."""
     return re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*custom-call\(", text)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("leaf", [MLP_LEAF, LM_LEAF], ids=["mlp", "lm"])
-@pytest.mark.parametrize("K", [16, 128])
+def _compile_cases():
+    leaves = {"mlp": MLP_LEAF, "lm": LM_LEAF}
+    dtypes = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+    cases = [pytest.param(K, leaves[l], dtypes[d], id=f"{K}-{l}-{d}")
+             for K in (16, 128) for l in leaves for d in dtypes]
+    # the widest leaf of the LM cell, whose lane block is what keeps the
+    # kernel inside its VMEM
+    return cases + [pytest.param(4, LM_HEAD, jnp.bfloat16,
+                                 id="4-lm_head-bf16")]
+
+
+@pytest.mark.parametrize("K,leaf,dtype", _compile_cases())
 def test_fused_event_apply_compiles(one_chip, K, leaf, dtype):
-    """The one-kernel apply at the K-event block the tile table picks."""
+    """The one-kernel apply at the blocks its VMEM budget gives, reading
+    the leaf in its own layout: no pad, one launch."""
     f32 = jnp.float32
     args = [_abstract(leaf, dtype, one_chip),
             _abstract((K,) + leaf, dtype, one_chip)]
@@ -100,6 +133,9 @@ def test_fused_event_apply_compiles(one_chip, K, leaf, dtype):
     text = _kernel_text(apply, *args)
     assert "tpu_custom_call" in text
     assert len(_launches(text, "fused_event_apply")) == 1
+    assert not _instructions(text, "pad")
+    if leaf == LM_HEAD:
+        assert ops.apply_blocks(*leaf, K, dtype, dtype)[1] < leaf[1]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -141,6 +177,28 @@ def test_fred_fused_step_holds_kernel(one_chip):
     assert "tpu_custom_call" in text
     # one launch per leaf of the MLP
     assert len(_launches(text, "fused_event_apply")) == 4
+    # W1's launch reads the backward's [K, 784, 200] gradient batch as the
+    # dot wrote it, and W2's (200 x 10, narrower than a lane tile) its
+    # transposed [K, 10, 200] one: nothing between client_grad and the kernel
+    K = cs.FRED_K
+    for batch in (f"f32[{K},{MLP_LEAF[0]},{MLP_LEAF[1]}]",
+                  f"f32[{K},{MLP_W2[1]},{MLP_W2[0]}]"):
+        launch = [l for l in re.findall(r"^\s*%fused_event_apply\S* = .*$",
+                                        text, re.M)
+                  if f"{batch}{{2,1,0}}}}" in l]
+        assert len(launch) == 1, f"no launch reads {batch} in place"
+        operands = re.search(r"custom-call\(([^)]*)\)", launch[0]).group(1)
+        grad = re.findall(r"%([\w.-]+)", operands)[-1]
+        opcode, shape, op_name = _producer(text, grad)
+        assert shape.startswith(batch) and opcode == "fusion", (opcode, shape)
+        assert "client_grad" in op_name and "transpose(" in op_name, op_name
+    # and the program holds no pad of an event batch, nor a copy of W1's
+    size = K * MLP_LEAF[0] * MLP_LEAF[1]
+    assert not [t for t, _ in _instructions(text, "pad").values()
+                if t.startswith(f"f32[{K},")]
+    assert not [t for t, _ in _instructions(text, "copy").values()
+                if t.startswith(f"f32[{K},") and math.prod(
+                    map(int, t[4:t.index("]")].split(","))) >= size]
 
 
 @pytest.mark.parametrize("axis", ["server", "clients"])
