@@ -436,10 +436,11 @@ def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
     measured from its own last synchronization; the per-leaf τ reaches the
     batched Pallas kernel as that leaf's SMEM τ vector).
 
-    `mesh` is the device mesh the state is placed on, if any: the kernel
-    then applies each leaf's `server_axis` block on the device that holds
-    it (`core/server_shard.py` routing; every leaf replicated when the mesh
-    has no such axis).
+    `mesh` is the device mesh the state is placed on, if any: the pushed
+    gradients are first routed to the server's blocks (``push_route``,
+    `server_shard.route_events`), and the kernel then applies each leaf's
+    `server_axis` block on the device that holds it (`core/server_shard.py`
+    routing; every leaf replicated when the mesh has no such axis).
 
     Returns (server, taus [K] — the per-event staleness, averaged over
     leaves in per-tensor mode).
@@ -451,6 +452,11 @@ def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
     per_leaf_push = is_per_leaf(push, server.params)
     per_leaf_ts = is_per_leaf(client_ts, server.params)
     track_stats = scfg.track_stats or rule.requires_stats
+    if mesh is not None:
+        from repro.core import server_shard
+        if server_shard.mesh_axis_size(mesh, server_axis) > 1:
+            with jax.named_scope("push_route"):
+                grads = server_shard.route_events(grads, mesh, server_axis)
 
     if per_leaf_push:
         pushf = jax.tree.map(lambda m: m.astype(jnp.float32), push)
@@ -536,7 +542,6 @@ def fused_apply(scfg: ServerConfig, server: ServerState, grads, push,
         from repro.kernels.ops import fused_event_apply
         leaf_specs = None
         if mesh is not None:
-            from repro.core import server_shard
             S = server_shard.mesh_axis_size(mesh, server_axis)
             leaf_specs = [server_shard.server_leaf_spec(p.shape, S, server_axis)
                           for p in jax.tree.leaves(server.params)]

@@ -52,7 +52,12 @@ and an explicit ``'cotangent'`` with a queue is rejected.
 state (and the ingress-queue payload) across a ``'server'`` mesh axis, so
 the canonical update runs with each shard owning its slice of W and the
 eq. 4–6 statistics — the same placement contract as FRED's
-``run_simulation(mesh=...)``; see docs/SHARDING.md.
+``run_simulation(mesh=...)``.  The clients lie over the same devices, C/S
+whole clients a device (C must be a multiple of the axis size S): their
+copies, timestamps and batches, and their forward and backward.  A push is
+then an exchange (``push_route``: each client's gradient split into the
+server's blocks), and so is a fetch (``fetch_gather``: the new W's blocks
+gathered into the fetching clients' copies); see docs/SHARDING.md.
 
 **Scenario-lite wall clock** (``TrainerConfig.scenario``,
 `core/scenarios.py`): each round the C clients draw modeled service times
@@ -70,6 +75,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import TrainerConfig
 from repro.core import engine
@@ -142,21 +148,57 @@ def init_round_state(tc: TrainerConfig, params) -> RoundState:
     )
 
 
+def round_state_shardings(state: RoundState, mesh,
+                          axis: str = server_shard.SERVER_AXIS) -> RoundState:
+    """The `NamedSharding` of every leaf of `state` (arrays or shapes, e.g.
+    from `jax.eval_shape`) on a sharded-server mesh.
+
+    ``state.server`` (W and the eq. 4–6 statistics) and the ingress-queue
+    payload are block-partitioned across the ``axis`` devices per
+    `server_shard.server_leaf_spec`.  The [C]-leading client leaves
+    (copies, ``client_ts``, ``client_leaf_ts``) lie over the axis too, C/S
+    whole clients a device (`server_shard.check_clients_divide`).  Scalars,
+    counters and the queue's slot bookkeeping replicate.
+    """
+    S = server_shard.mesh_axis_size(mesh, axis)
+    server_shard.check_clients_divide(jnp.shape(state.client_ts)[0], mesh,
+                                      axis)
+    at = lambda spec: NamedSharding(mesh, spec)
+    everywhere = lambda tree: jax.tree.map(
+        lambda _: at(PartitionSpec()), tree)
+
+    def blocks(tree, lead=0):
+        return jax.tree.map(lambda l: at(server_shard.server_leaf_spec(
+            jnp.shape(l), S, axis, lead=lead)), tree)
+
+    clients = lambda tree: jax.tree.map(lambda l: at(
+        server_shard.client_leaf_spec(jnp.ndim(l), axis) if S > 1
+        else PartitionSpec()), tree)
+    queue = None
+    if state.queue is not None:
+        queue = everywhere(state.queue)._replace(
+            payload=blocks(state.queue.payload, lead=1))
+    return RoundState(
+        server=blocks(state.server),
+        client_params=clients(state.client_params),
+        client_ts=clients(state.client_ts),
+        round_idx=everywhere(state.round_idx),
+        counters=everywhere(state.counters),
+        client_leaf_ts=clients(state.client_leaf_ts),
+        queue=queue,
+    )
+
+
 def shard_round_state(state: RoundState, mesh,
                       axis: str = server_shard.SERVER_AXIS) -> RoundState:
-    """Place a `RoundState`'s server partition on a sharded-server mesh.
-
-    Block-partitions ``state.server`` (W and the eq. 4–6 statistics) and the
-    ingress-queue payload across the ``axis`` devices of ``mesh`` via
-    `core.server_shard`; the [C]-leading client copies stay replicated (they
-    are the *fleet*, sharded separately by a client axis).  A mesh whose
-    ``axis`` has size 1 (or no ``axis``) is a no-op, preserving the
-    ``server_shards=1`` bitwise contract.
+    """Place a `RoundState` on a sharded-server mesh
+    (`round_state_shardings`).  A mesh whose ``axis`` has size 1 (or no
+    ``axis``) is a no-op, preserving the ``server_shards=1`` bitwise
+    contract.
     """
-    return state._replace(
-        server=server_shard.shard_server_state(state.server, mesh, axis),
-        queue=server_shard.shard_queue_state(state.queue, mesh, axis),
-    )
+    if server_shard.mesh_axis_size(mesh, axis) <= 1:
+        return state
+    return jax.device_put(state, round_state_shardings(state, mesh, axis))
 
 
 def build_round_step(
@@ -169,8 +211,14 @@ def build_round_step(
     """Returns round_step(state, batch, key) -> (state, metrics).
 
     `batch` leaves must have a leading [C] axis (one shard per client group).
-    `mesh` is the mesh `shard_round_state` placed the server on, if any; the
-    one-kernel apply then runs each shard's block on its own device.
+    `mesh` is the mesh `shard_round_state` placed the state on, if any; the
+    one-kernel apply then runs each shard's block on its own device.  On a
+    server axis of S > 1 devices the clients lie over it too
+    (`round_state_shardings`; C a multiple of S): each device computes the
+    forward and backward of its own clients alone (a `jax.shard_map` over
+    the client axis), the pushed gradients are routed to the server's
+    blocks (``push_route``, in the apply) and the new W's blocks gathered
+    into the fetching copies (``fetch_gather``).
 
     With ``apply_mode='fused'`` and ``tc.fused_mode`` 'auto'/'cotangent' the
     per-client gradients are reduced by the engine's cotangent path when the
@@ -285,6 +333,19 @@ def build_round_step(
             "gating, drop_policy='discard', use_fused_kernel=False, and an "
             "event-batched loss (batched_loss_fn or grad_fn.event_batched)")
 
+    axis = tc.server_axis
+    server_shard.check_clients_divide(tc.num_round_clients, mesh, axis)
+    spread = server_shard.mesh_axis_size(mesh, axis) > 1
+    vgrad = jax.vmap(grad_fn)
+    if spread:
+        # each device runs its own clients' forward and backward on the
+        # copies and batch rows it holds: no client's work is repeated
+        on_clients = PartitionSpec(axis)
+        vgrad = jax.shard_map(vgrad, mesh=mesh,
+                              in_specs=(on_clients, on_clients),
+                              out_specs=(on_clients, on_clients),
+                              check_vma=False)
+
     @jax.named_scope("dispatch")
     def round_step(state: RoundState, batch, key):
         k_push, k_fetch = jax.random.split(key)
@@ -302,7 +363,7 @@ def build_round_step(
 
         if not use_cotangent:
             with jax.named_scope("client_grad"):
-                losses, grads = jax.vmap(grad_fn)(state.client_params, batch)
+                losses, grads = vgrad(state.client_params, batch)
         else:
             grads = None        # cotangent: losses come from the vjp forward
 
@@ -439,7 +500,16 @@ def build_round_step(
             # the un-pushed local gradient is never needed there.
             local = cp - tc.lr * g if tc.drop_policy == "local_apply" else cp
             kept = jnp.where(p, cp, local)       # un-pushed grad applied locally
-            return jnp.where(f, sp[None], kept)  # fetched clients get canonical
+            if not spread:
+                return jnp.where(f, sp[None], kept)  # fetched get canonical
+            with jax.named_scope("fetch_gather"):
+                # the server's blocks of the new W, gathered whole onto
+                # the devices of the clients that fetch them
+                sp = jax.lax.with_sharding_constraint(
+                    sp, NamedSharding(mesh, PartitionSpec()))
+            new = jnp.where(f, sp[None], kept)
+            return jax.lax.with_sharding_constraint(new, NamedSharding(
+                mesh, server_shard.client_leaf_spec(new.ndim, axis)))
 
         # with a queue, a push the admission policy refused behaves like a
         # gated-out push on the client: it falls back to drop_policy

@@ -44,6 +44,13 @@ gate RNG streams are placement-independent: every Bernoulli draw consumes
 its key whether or not the transmit happens, and per-tensor draws are
 keyed per leaf, never per shard.
 
+**Clients on the server's devices** (`client_leaf_spec`,
+`check_clients_divide`, `shard_clients`, `route_events`): the round
+trainer's C clients, a multiple of the axis size, lie over it too: their
+[C]-leading copies, timestamps and batches, C/S whole clients a device.  A
+push then routes each client's gradient to the blocks (`route_events`), and a
+fetch gathers the blocks back into the copies.
+
 The realized dataflow (push → route → shard-apply → fetch, per-shard
 ingress queue and one-kernel launches included) is documented in
 ``docs/SHARDING.md``.
@@ -98,8 +105,8 @@ def mesh_axis_size(mesh, axis: str = SERVER_AXIS) -> int:
     return int(mesh.shape[axis])
 
 
-def server_leaf_spec(shape, num_shards: int,
-                     axis: str = SERVER_AXIS) -> PartitionSpec:
+def server_leaf_spec(shape, num_shards: int, axis: str = SERVER_AXIS,
+                     lead: int = 0) -> PartitionSpec:
     """Block-routing spec for one server leaf of static `shape`.
 
     Mirrors `sharding.rules.leaf_param_spec`: scanning dimensions from the
@@ -109,11 +116,13 @@ def server_leaf_spec(shape, num_shards: int,
     self-contained slice of server state).  Leaves with no divisible
     dimension (tiny biases) replicate: P().  ``num_shards <= 1`` always
     replicates, which is what makes the S=1 path bitwise-identical to the
-    unsharded server.
+    unsharded server.  `lead` leading dims of `shape` are event or slot axes
+    (a [K, *leaf] gradient batch, a [capacity, *leaf] queue payload): they
+    stay whole and the rest routes like the leaf.
     """
     if num_shards <= 1:
         return PartitionSpec()
-    for dim in range(len(shape) - 1, -1, -1):
+    for dim in range(len(shape) - 1, lead - 1, -1):
         if shape[dim] >= num_shards and shape[dim] % num_shards == 0:
             spec = [None] * len(shape)
             spec[dim] = axis
@@ -153,6 +162,45 @@ class ServerShardPlan(NamedTuple):
     def peak_resident_bytes(self) -> int:
         """Max over shards of `resident_bytes` — the BENCH headline number."""
         return max(self.resident_bytes(s) for s in range(self.num_shards))
+
+
+def client_leaf_spec(ndim: int, axis: str = SERVER_AXIS) -> PartitionSpec:
+    """Spec of a [C]-leading client leaf (a copy, a timestamp, a batch)
+    whose client axis lies over `axis`: each device holds C/S whole
+    clients, one client a device when C == S."""
+    return PartitionSpec(axis, *([None] * (ndim - 1)))
+
+
+def check_clients_divide(num_clients: int, mesh,
+                         axis: str = SERVER_AXIS) -> None:
+    """Raise ValueError unless the C clients divide over the S devices of
+    `mesh[axis]`: on a sharded server they lie over those devices, C/S
+    whole clients a device, and a client cannot straddle two."""
+    size = mesh_axis_size(mesh, axis)
+    if size > 1 and num_clients % size:
+        raise ValueError(
+            f"num_round_clients={num_clients} is not a multiple of the "
+            f"{size} devices of the {axis!r} axis: the clients lie over the "
+            f"server's devices, C/S whole clients a device, and a client "
+            f"cannot straddle two")
+
+
+def shard_clients(tree, mesh, axis: str = SERVER_AXIS):
+    """Place every [C]-leading leaf of `tree` with its client axis over
+    `mesh[axis]` (`client_leaf_spec`)."""
+    return jax.tree.map(lambda l: jax.device_put(l, NamedSharding(
+        mesh, client_leaf_spec(jnp.ndim(l), axis))), tree)
+
+
+def route_events(tree, mesh, axis: str = SERVER_AXIS):
+    """Route an event batch to the server's blocks: every [K, *leaf] leaf
+    of `tree` constrained to its leaf's `server_leaf_spec` on the trailing
+    dims, the K events whole on every device.  Where the events were
+    computed on the clients' devices this is the push's exchange."""
+    size = mesh_axis_size(mesh, axis)
+    return jax.tree.map(lambda g: jax.lax.with_sharding_constraint(
+        g, NamedSharding(mesh, server_leaf_spec(jnp.shape(g), size, axis,
+                                                lead=1))), tree)
 
 
 def make_shard_plan(tree, num_shards: int,
@@ -233,10 +281,9 @@ def shard_tree(tree, mesh, axis: str = SERVER_AXIS, *, batch_dims: int = 0):
     num_shards = mesh_axis_size(mesh, axis)
 
     def put(leaf):
-        spec = server_leaf_spec(jnp.shape(leaf)[batch_dims:], num_shards,
-                                axis)
-        full = PartitionSpec(*([None] * batch_dims + list(spec)))
-        return jax.device_put(leaf, NamedSharding(mesh, full))
+        spec = server_leaf_spec(jnp.shape(leaf), num_shards, axis,
+                                lead=batch_dims)
+        return jax.device_put(leaf, NamedSharding(mesh, spec))
 
     return jax.tree.map(put, tree)
 
@@ -320,11 +367,15 @@ def validate_server_mesh(mesh, num_shards: int,
 __all__ = [
     "SERVER_AXIS",
     "ServerShardPlan",
+    "check_clients_divide",
+    "client_leaf_spec",
     "count_shard",
     "make_shard_plan",
     "mesh_axis_size",
     "peak_shard_bytes",
+    "route_events",
     "server_leaf_spec",
+    "shard_clients",
     "shard_queue_state",
     "shard_server_state",
     "shard_tree",

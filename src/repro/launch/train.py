@@ -14,6 +14,7 @@ Modes:
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -25,7 +26,7 @@ from repro.core import rules as server_rules
 from repro.core import scenarios
 from repro.core import server_shard
 from repro.core.round_trainer import (
-    build_round_step, init_round_state, shard_round_state)
+    build_round_step, init_round_state, round_state_shardings)
 from repro.data.tokens import TokenDataConfig, make_batch as make_token_batch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_server_mesh
@@ -52,11 +53,14 @@ def run_round_trainer(cfg, tc: TrainerConfig, params, *, apply_mode: str,
                       scenario_name: str = "off") -> dict:
     """Round-trainer training loop (the CLI's ``--clients C > 0`` mode).
 
-    Builds the round state for ``tc`` around ``params``, shards the server
-    when ``tc.server_shards > 1``, compiles one round step ahead of time,
-    and runs rounds ``[start, steps)`` on synthetic batches of ``batch``
-    sequences of ``seq`` tokens, split over the ``tc.num_round_clients``
-    client groups (``start`` > 0 when resuming from ``ckpt_dir``).  Prints
+    Builds the round state for ``tc`` around ``params``, placed where the
+    step runs: when ``tc.server_shards > 1`` the server sharded and the
+    clients and their batches over the same devices, C/S whole clients a
+    device (`round_trainer.round_state_shardings`).  Compiles one round
+    step ahead of time, with the state donated, and runs rounds
+    ``[start, steps)`` on synthetic batches of ``batch`` sequences of
+    ``seq`` tokens, split over the ``tc.num_round_clients`` client groups
+    (``start`` > 0 when resuming from ``ckpt_dir``).  Prints
     the CLI's log lines, the compile time apart from tracing and lowering
     (what a persistent-cache hit saves), and returns ``{"state", "losses",
     "compiled"}``: ``losses`` holds one mean loss per round run, and
@@ -82,24 +86,34 @@ def run_round_trainer(cfg, tc: TrainerConfig, params, *, apply_mode: str,
         raise ValueError(f"global batch {batch} must divide over {C} clients")
     Bc = batch // C
 
-    state = init_round_state(tc, params)
-    smesh = None
+    init = functools.partial(init_round_state, tc)
+    smesh = placed = None
     if tc.server_shards > 1:
         smesh = make_server_mesh(server=tc.server_shards)
         server_shard.validate_server_mesh(
             smesh, tc.server_shards, tc.server_axis)
-        state = shard_round_state(state, smesh, tc.server_axis)
+        placed = round_state_shardings(
+            jax.eval_shape(init, params), smesh, tc.server_axis)
         print(f"[train] server sharded: {tc.server_shards} shards on "
-              f"axis '{tc.server_axis}' (mesh {dict(smesh.shape)})")
+              f"axis '{tc.server_axis}' (mesh {dict(smesh.shape)}), "
+              f"clients over the same devices")
+    # built by one jitted call, so every leaf has a buffer of its own for
+    # the step to donate, each made where the step holds it
+    state = jax.jit(init, **({"out_shardings": placed} if placed else {}))(
+        params)
 
     start = 0
     if ckpt_dir and latest_step(ckpt_dir) is not None:
         state, start, _ = restore_checkpoint(ckpt_dir, state)
+        if placed:
+            state = jax.device_put(state, placed)
         print(f"[train] resumed from step {start}")
 
     def client_batch(step):
         flat = batch_for_step(cfg, batch, seq, step)
-        return jax.tree.map(lambda l: l.reshape((C, Bc) + l.shape[1:]), flat)
+        out = jax.tree.map(lambda l: l.reshape((C, Bc) + l.shape[1:]), flat)
+        return (server_shard.shard_clients(out, smesh, tc.server_axis)
+                if smesh is not None else out)
 
     def round_key(step):
         return jax.random.fold_in(jax.random.PRNGKey(tc.seed), step)
@@ -108,7 +122,7 @@ def run_round_trainer(cfg, tc: TrainerConfig, params, *, apply_mode: str,
     lowered = jax.jit(build_round_step(
         tc, grad_fn, apply_mode=apply_mode, batched_loss_fn=batched_loss_fn,
         mesh=smesh,
-    )).lower(state, client_batch(start), round_key(start))
+    ), donate_argnums=0).lower(state, client_batch(start), round_key(start))
     t1 = time.perf_counter()
     step_fn = lowered.compile()
     compile_s = time.perf_counter() - t1
@@ -264,6 +278,11 @@ def main():
         ap.error("--scenario needs the round trainer (--clients C > 0)")
     if args.server_shards > 1 and args.clients <= 0:
         ap.error("--server-shards needs the round trainer (--clients C > 0)")
+    if args.server_shards > 1 and args.clients % args.server_shards:
+        ap.error(f"--clients {args.clients} is not a multiple of "
+                 f"--server-shards {args.server_shards}: the clients lie "
+                 f"over the server's devices, C/S whole clients a device, "
+                 f"and a client cannot straddle two")
     kasync_k = args.kasync_k
     if args.rule == "kasync" and kasync_k == 0:
         # a full-barrier default would make kasync ≡ ssgd; half the fleet
@@ -285,7 +304,10 @@ def main():
         seed=args.seed,
     )
     mesh = make_host_mesh(data=len(jax.devices()))
-    set_mesh_context(mesh)
+    if args.server_shards <= 1:
+        # a sharded server places each client on a device of its own mesh,
+        # where the model's activation constraints have nothing to split
+        set_mesh_context(mesh)
 
     params = init_model(jax.random.PRNGKey(args.seed), cfg)
     print(f"[train] {cfg.name}: {param_count(params):,} params, "

@@ -129,3 +129,24 @@ def test_dryrun_pair_list_covers_assignment():
     assert "attn_window" not in ov.get(("mamba2-1.3b", "long_500k"), {})
     # train pairs get remat
     assert ov[("yi-34b", "train_4k")]["remat"] is True
+
+
+@pytest.mark.parametrize("clients,shards", [(2, 4), (6, 4)])
+def test_train_cli_refuses_clients_that_straddle_shards(clients, shards):
+    """`--server-shards S` lays the clients over the server's S devices,
+    C/S whole clients a device; a C that S does not divide is refused
+    before anything is built, with the reason."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--smoke",
+         "--clients", str(clients), "--server-shards", str(shards)],
+        capture_output=True, text=True, env=env, timeout=120)
+    assert r.returncode == 2
+    assert (f"--clients {clients} is not a multiple of --server-shards "
+            f"{shards}") in r.stderr
+    assert "straddle" in r.stderr

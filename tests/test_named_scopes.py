@@ -9,7 +9,11 @@ interpret mode so that its views of the leaves are lowered too, and looks for ev
 scope the program's path must carry.
 """
 import math
+import os
 import re
+import subprocess
+import sys
+import textwrap
 
 import jax
 import pytest
@@ -143,3 +147,64 @@ def test_round_step_scopes():
     assert not missing, f"scopes missing from the round step: {missing}"
     assert any(n.startswith("jit(round_step)/dispatch/") for n in names)
     _assert_pack_is_views(lowered, params)
+
+
+_SHARDED_ROUND = textwrap.dedent("""
+    import re
+    import jax
+    from repro.configs.base import TrainerConfig
+    from repro.core.round_trainer import (
+        build_round_step, init_round_state, shard_round_state)
+    from repro.launch.mesh import make_server_mesh
+    from repro.models.mlp import init_mlp, nll_loss
+
+    C, MU, SIZES = 4, 3, (16, 8, 4)
+    params = init_mlp(jax.random.PRNGKey(0), SIZES)
+    x = jax.random.normal(jax.random.PRNGKey(1), (C, MU, SIZES[0]))
+    y = jax.random.randint(jax.random.PRNGKey(2), (C, MU), 0, SIZES[-1])
+    tc = TrainerConfig(num_round_clients=C, rule="fasgd", lr=0.02,
+                       use_fused_kernel=True, kernel_interpret=True,
+                       server_shards=4)
+    mesh = make_server_mesh(server=4)
+
+    def grad_fn(p, batch):
+        return jax.value_and_grad(nll_loss)(p, *batch)
+
+    state = shard_round_state(init_round_state(tc, params), mesh)
+    step = build_round_step(tc, grad_fn, apply_mode="fused", mesh=mesh)
+    text = jax.jit(step, donate_argnums=0).lower(
+        state, (x, y), jax.random.PRNGKey(4)).compile().as_text()
+    for line in text.splitlines():
+        m = re.search(r" (all-to-all|all-gather)\\(.*op_name=\\"([^\\"]*)\\"",
+                      line)
+        if m:
+            print("COLLECTIVE", m.group(1), m.group(2))
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        print("OP", name)
+""")
+
+
+def test_sharded_round_step_scopes():
+    """With the clients over the sharded server's four devices, the push's
+    route of the gradients to the server's blocks runs under
+    `server_apply/push_route` and the fetch's gather of the new W under
+    `fetch_refresh/fetch_gather`, each an exchange between the devices."""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", _SHARDED_ROUND],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
+    lines = r.stdout.splitlines()
+    names = {l[3:] for l in lines if l.startswith("OP ")}
+    missing = ({"dispatch", "client_grad", "server_apply", "fetch_refresh",
+                "push_route", "fetch_gather"} - _scopes(names))
+    assert not missing, f"scopes missing from the sharded round: {missing}"
+    colls = [l.split()[1:] for l in lines if l.startswith("COLLECTIVE ")]
+    assert any(op == "all-to-all" and "server_apply/push_route" in n
+               for op, n in colls), colls
+    assert any(op == "all-gather" and "fetch_refresh/fetch_gather" in n
+               for op, n in colls), colls
