@@ -20,6 +20,7 @@ from jax.sharding import PartitionSpec
 
 from repro.kernels import batched_update as _bk
 from repro.kernels import fasgd_update as _fk
+from repro.kernels import fetch_rows as _fr
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_event_apply as _fe
 from repro.kernels.ref import attention_ref, fused_event_apply_ref
@@ -237,15 +238,16 @@ def batched_scale_apply(params: Any, grads: Any, v: Any, coeffs, taus,
     return jax.tree.unflatten(params_def, outs)
 
 
-def _fused_event_path(interpret) -> str:
-    """Dispatch for `fused_event_apply`: 'pallas' | 'interpret' | 'xla'.
+def _kernel_path(interpret) -> str:
+    """Dispatch for `fused_event_apply` and `fetch_rows`: 'pallas' |
+    'interpret' | 'xla'.
 
     Explicit True forces the Pallas kernel in interpret mode (CPU-testable
     kernel body — CI correctness); explicit False forces the native compile;
-    None auto-detects — native Pallas on TPU, otherwise the XLA streaming
-    reference (`ref.fused_event_apply_ref`), which has the same semantics
-    but realistic off-TPU *timing* (interpret mode is an emulator, far too
-    slow to benchmark).
+    None auto-detects — native Pallas on TPU, otherwise the XLA form
+    (`ref.fused_event_apply_ref`; the fetch's scatter), which
+    has the same semantics but realistic off-TPU *timing* (interpret mode
+    is an emulator, far too slow to benchmark).
     """
     if interpret is None:
         interpret = _env_interpret()
@@ -279,7 +281,7 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     float32.  The kernel reads and writes each leaf in its own layout
     (`_kernel_view`), in blocks that `apply_blocks` derives from the leaf's
     shape, its dtypes and K; `block_rows` > 0 overrides the row block.
-    `interpret` dispatches per `_fused_event_path`.
+    `interpret` dispatches per `_kernel_path`.
 
     The compiler cannot partition a Mosaic kernel, so under a device `mesh`
     each leaf's launch runs inside `jax.shard_map`: every device applies
@@ -289,7 +291,7 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     vectors replicated.  The kernel is elementwise over the leaf, so a
     block applies on its own.
     """
-    path = _fused_event_path(interpret)
+    path = _kernel_path(interpret)
     K = jax.tree.leaves(grads)[0].shape[0]
     params_def = jax.tree.structure(params)
 
@@ -348,6 +350,49 @@ def fused_event_apply(params: Any, grads: Any, n: Any, b: Any, v: Any,
     unzip = tuple(jax.tree.unflatten(params_def, [o[i] for o in outs])
                   for i in range(4))
     return unzip  # (params, n, b, v)
+
+
+def fetch_into_fleet(fleet: Any, server: Any, rows: Any, *,
+                     sharded: bool = False, interpret: bool | None = None):
+    """FRED's fetch: rows `rows` of every [λ, …] fleet leaf take the
+    matching leaf of `server`.
+
+    `rows` is one int32 [K] vector for every leaf or a pytree of them
+    mirroring `fleet`, unique below λ, an index ≥ λ dropped.  Where
+    `_kernel_path(interpret)` is a kernel and the fleet is not `sharded`
+    (a custom call on a sharded fleet would be all-gathered), the leaves
+    that `fetch_rows.launches` takes (a row of rank ≥ 2 that fits the VMEM
+    budget: the MLP's W1 and W2) are written by `fetch_rows.fetch_rows`,
+    one launch for as many of them as fit the budget together.  The others
+    (a bias, whose row is one sublane of a tile) take XLA's scatter of the
+    broadcast leaf.
+
+    A row whose tiles hold it with less padding with its last two dims
+    swapped is written swapped: XLA keeps such a fleet in that layout (the
+    MLP's 784 × 200 W1 and 200 × 10 W2 with the 784 and the 200 on the
+    lanes), and the swap then folds into it.
+    """
+    leaves, treedef = jax.tree.flatten(fleet)
+    servers = treedef.flatten_up_to(server)
+    rows = (treedef.flatten_up_to(rows)
+            if jax.tree.structure(rows) == treedef else [rows] * len(leaves))
+    path = "xla" if sharded else _kernel_path(interpret)
+    tiled = _fr.tiled_bytes
+    flips = [s.ndim >= 2 and tiled(s.shape[:-2] + s.shape[:-3:-1], s.dtype)
+             < tiled(s.shape, s.dtype) for s in servers]
+    swap = lambda x, flip: jnp.swapaxes(x, -1, -2) if flip else x
+    written = {}
+    for group in (_fr.launches([swap(s, k) for s, k in zip(servers, flips)])
+                  if path != "xla" else []):
+        outs = _fr.fetch_rows([swap(leaves[i], flips[i]) for i in group],
+                              [swap(servers[i], flips[i]) for i in group],
+                              [rows[i] for i in group],
+                              interpret=path == "interpret")
+        written.update((i, swap(o, flips[i])) for i, o in zip(group, outs))
+    out = [written[i] if i in written else leaf.at[r].set(
+               jnp.broadcast_to(s[None], r.shape + s.shape), mode="drop")
+           for i, (leaf, s, r) in enumerate(zip(leaves, servers, rows))]
+    return jax.tree.unflatten(treedef, out)
 
 
 def attention(q, k, v, *, causal=True, window=0, sm_scale=None,

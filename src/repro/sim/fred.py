@@ -104,6 +104,7 @@ from repro.core.engine import (
     tree_where_axis,
 )
 from repro.core.rules import ServerConfig, ServerState
+from repro.kernels import ops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -426,6 +427,16 @@ def _dispatch(config: SimConfig, rr_pos, key, het_logits):
     return jax.random.categorical(key, het_logits)
 
 
+def fetch_rows_of(cs, fetched, lam):
+    """The fleet rows a window's fetch writes: client `cs[k]` where event k
+    fetched and no later event of the window fetches for the same client,
+    else λ (dropped), so every row is written once.  `fetched` is one [K]
+    mask or a per-leaf pytree of them (per-tensor fetch)."""
+    return jax.tree.map(
+        lambda m: jnp.where(engine.last_event_winners(cs, m), cs, lam),
+        fetched)
+
+
 def _build_queue_step(config: SimConfig, loss_fn, data_x, data_y, K,
                       batched_loss_fn=None, mesh=None):
     """step(state, keys) for the queued protocol: one drain window per call.
@@ -587,13 +598,7 @@ def _build_queue_step(config: SimConfig, loss_fn, data_x, data_y, K,
                 k, new_server, bw.c_fetch, bw.eps)[0])(k_fetch)  # leaves [K]
             fetch = jnp.stack(jax.tree.leaves(fmask)).all(axis=0)  # [K]
             fetch_sent = masked_bytes(fmask, new_server.params)
-
-            def fetch_leaf(m, cl, sp):
-                i = jnp.where(m, cs, lam)            # dropped when ¬fetched
-                return cl.at[i].set(
-                    jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop")
-            client_params = jax.tree.map(
-                fetch_leaf, fmask, state.client_params, new_server.params)
+            fetched = fmask
             leaf_cols = []
             for i, m in enumerate(jax.tree.leaves(fmask)):
                 rows = jnp.where(m, cs, lam)
@@ -606,12 +611,13 @@ def _build_queue_step(config: SimConfig, loss_fn, data_x, data_y, K,
             fetch = jax.vmap(lambda k: engine.transmit_gate(
                 k, new_server, bw.c_fetch, bw.eps))(k_fetch)     # [K]
             fetch_sent = jnp.sum(fetch.astype(jnp.float32)) * model_bytes
-            fidx = jnp.where(fetch, cs, lam)           # dropped when ¬fetch
-            client_params = jax.tree.map(
-                lambda cl, sp: cl.at[fidx].set(
-                    jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop"),
-                state.client_params, new_server.params)
+            fetched = fetch
             client_leaf_ts = state.client_leaf_ts
+        client_params = ops.fetch_into_fleet(
+            state.client_params, new_server.params,
+            fetch_rows_of(cs, fetched, lam),
+            sharded=config.server_shards > 1,
+            interpret=scfg.kernel_interpret)
         fetch_idx = jnp.where(fetch, cs, lam)
         client_ts = state.client_ts.at[fetch_idx].set(
             jnp.broadcast_to(new_server.timestamp, (K,)), mode="drop")
@@ -941,6 +947,7 @@ def build_step_fn(
             in_specs=(spec, spec, spec), out_specs=(spec, spec),
             check_vma=False)
     vgrad = jax.named_scope("client_grad")(vgrad)
+    fetch_sharded = client_mesh is not None or config.server_shards > 1
 
     @jax.named_scope("dispatch")
     def step(state: SimState, keys):
@@ -1041,22 +1048,16 @@ def build_step_fn(
             grad_cache = None
 
         # --- fetch gates (post-apply server state) ---
-        # Every fetch delivers the same canonical parameters, so duplicate
-        # clients in the batch all write identical rows — the scatters are
-        # deterministic and touch K rows, never the full λ fleet.
+        # Every fetch delivers the same canonical parameters: each fetched
+        # client's row is written once (`fetch_rows_of`), K rows, never the
+        # full λ fleet.
         with jax.named_scope("fetch_scatter"):
             if bw.per_tensor_fetch:
                 fmask = jax.vmap(lambda k: engine.per_tensor_gate(
                     k, new_server, bw.c_fetch, bw.eps)[0])(k_fetch)  # leaves [K]
                 fetch = jnp.stack(jax.tree.leaves(fmask)).all(axis=0)  # [K]
                 fetch_sent = masked_bytes(fmask, new_server.params)
-
-                def fetch_leaf(m, cp, sp):
-                    i = jnp.where(m, cs, lam)            # dropped when ¬fetched
-                    return cp.at[i].set(
-                        jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop")
-                client_params = jax.tree.map(
-                    fetch_leaf, fmask, state.client_params, new_server.params)
+                fetched = fmask
                 leaf_cols = []
                 for i, m in enumerate(jax.tree.leaves(fmask)):
                     rows = jnp.where(m, cs, lam)
@@ -1069,12 +1070,12 @@ def build_step_fn(
                 fetch = engine.transmit_gate(
                     k_fetch[0], new_server, bw.c_fetch, bw.eps, shape=(K,))
                 fetch_sent = jnp.sum(fetch.astype(jnp.float32)) * model_bytes
-                idx = jnp.where(fetch, cs, lam)            # dropped when ¬fetch
-                client_params = jax.tree.map(
-                    lambda cp, sp: cp.at[idx].set(
-                        jnp.broadcast_to(sp[None], (K,) + sp.shape), mode="drop"),
-                    state.client_params, new_server.params)
+                fetched = fetch
                 client_leaf_ts = state.client_leaf_ts
+            client_params = ops.fetch_into_fleet(
+                state.client_params, new_server.params,
+                fetch_rows_of(cs, fetched, lam), sharded=fetch_sharded,
+                interpret=scfg.kernel_interpret)
             fetch_idx = jnp.where(fetch, cs, lam)
             client_ts = state.client_ts.at[fetch_idx].set(
                 jnp.broadcast_to(new_server.timestamp, (K,)), mode="drop")

@@ -127,6 +127,20 @@ def test_fred_kernel_apply_packs_under_server_apply():
     assert kernel and all("server_apply" in n for n in kernel)
 
 
+@pytest.mark.parametrize("rule,fused_mode,kernel", [
+    ("fasgd", "materialized", True),
+    ("asgd", "cotangent", False),
+], ids=["fused_fasgd", "cotangent_asgd"])
+def test_fred_fetch_kernel_under_fetch_scatter(rule, fused_mode, kernel):
+    """The fetch's row-write kernel (`name="fetch_rows"`, interpret mode
+    here) runs under `fetch_scatter`, so a device trace puts its time down
+    to the fetch and not to the apply."""
+    names = _op_names(_fred_window(rule, fused_mode, kernel))
+    fetch = [n for n in names if "fetch_rows" in n]
+    assert fetch and all("dispatch/fetch_scatter/fetch_rows" in n
+                         for n in fetch)
+
+
 def test_round_step_scopes():
     params = init_mlp(jax.random.PRNGKey(0), SIZES)
     C = 4

@@ -153,15 +153,39 @@ def test_fasgd_update_compiles(one_chip, dtype):
     assert len(_launches(text, "fasgd_update")) == 1
 
 
-def _fred_step_text(cfg, mesh, place, sharding):
-    """Compile one scanned window of `cfg`'s fused FRED step, its state
-    placed by `place` (abstract leaf -> sharding)."""
+@pytest.mark.parametrize("lam,rows,dtype,launches", [
+    pytest.param(5000, (MLP_LEAF, MLP_W2), jnp.float32, 1, id="mlp"),
+    pytest.param(8, (LM_LEAF,), jnp.bfloat16, 1, id="lm-chunked"),
+    pytest.param(8, ((4,) + MLP_LEAF,) * 5, jnp.float32, 3,
+                 id="many-leaves"),
+    pytest.param(4, ((2,) + LM_LEAF,), jnp.float32, 0, id="slice-past-vmem"),
+])
+def test_fetch_rows_compiles(one_chip, lam, rows, dtype, launches):
+    """The fetch's row write: the MLP fleet's two weight matrices in one
+    launch, a leaf past the VMEM budget in chunks, leaves that do not fit
+    the budget together split across launches, and a leaf whose smallest
+    chunk (one [2048, 5632] f32 layer slice) does not fit at all left to
+    XLA's scatter."""
+    K = 128
+    args = ([_abstract((lam,) + r, dtype, one_chip) for r in rows],
+            [_abstract(r, dtype, one_chip) for r in rows],
+            [_abstract((K,), jnp.int32, one_chip) for _ in rows])
+    text = jax.jit(
+        lambda f, s, r: ops.fetch_into_fleet(f, s, r, interpret=False),
+        donate_argnums=0).lower(*args).compile().as_text()
+    assert len(_launches(text, "fetch_rows")) == launches
+
+
+def _fred_step_text(cfg, mesh, place, sharding, windows=1):
+    """Compile `windows` scanned windows of `cfg`'s fused FRED step, its
+    state placed by `place` (abstract leaf -> sharding)."""
     ds = make_synth_mnist(0, n_train=1024, n_valid=16)
     params = init_mlp(jax.random.PRNGKey(0))
     step = build_step_fn(cfg, nll_loss, ds.x_train, ds.y_train, mesh=mesh)
     state = jax.eval_shape(lambda p: init_sim(cfg, p), params)
     state = place(state)
-    keys = _abstract((1, cfg.events_per_step, 2), jnp.uint32, sharding)
+    keys = _abstract((windows, cfg.events_per_step, 2), jnp.uint32,
+                     sharding)
     return _kernel_text(lambda s, k: jax.lax.scan(step, s, k), state, keys)
 
 
@@ -199,6 +223,80 @@ def test_fred_fused_step_holds_kernel(one_chip):
     assert not [t for t, _ in _instructions(text, "copy").values()
                 if t.startswith(f"f32[{K},") and math.prod(
                     map(int, t[4:t.index("]")].split(","))) >= size]
+
+
+def _computation(text, name):
+    """The body of the compiled program's computation `name`."""
+    m = re.search(rf"^%{re.escape(name)} .*?^}}", text, re.M | re.S)
+    return m.group(0)
+
+
+@pytest.mark.parametrize("rule", ["fasgd", "asgd"])
+def test_fred_fetch_writes_rows(one_chip, rule):
+    """The fetch of the FRED cells' fused step (λ = 5000, K = 128): one
+    launch of the row-DMA kernel a window writes the new W1 and W2 into the
+    fetched rows.  The fetch broadcasts no K copies of W1, and the scan
+    body holds nothing of the fleet's W1 size but the kernel's output: no
+    scatter, no copy, no fusion that writes it."""
+    cs = _chip_smoke()
+    lam, K = 5000, cs.FRED_K
+    cfg = cs.fred_config(lam=lam, K=K, rule=rule,
+                         use_fused_kernel=(rule == "fasgd"),
+                         kernel_interpret=False)
+    place = lambda tree: jax.tree.map(
+        lambda x: _abstract(x.shape, x.dtype, one_chip), tree)
+    text = _fred_step_text(cfg, None, place, one_chip, windows=2)
+    body = _computation(text, re.search(r" while\(.*body=%([\w.-]+)",
+                                        text).group(1))
+    launch, = re.findall(r"^\s*%fetch_rows\S* = .*custom-call\(.*$", body,
+                         re.M)
+    assert "/fetch_scatter/fetch_rows/" in launch
+    w1 = {f"f32[{K},{MLP_LEAF[0]},{MLP_LEAF[1]}]",
+          f"f32[{K},{MLP_LEAF[1]},{MLP_LEAF[0]}]"}
+    assert not [t for t, line in _instructions(text, "broadcast").values()
+                if t.split("{")[0] in w1 and "fetch_scatter" in line]
+    fleet = {f"f32[{lam},{MLP_LEAF[0]},{MLP_LEAF[1]}]",
+             f"f32[{lam},{MLP_LEAF[1]},{MLP_LEAF[0]}]"}
+    written = re.findall(r"^\s*(?:ROOT )?%\S+ = (f32\[[\d,]+\])\S* "
+                         r"([\w-]+)\(", body, re.M)
+    assert not [op for t, op in written if t in fleet and op not in (
+        "parameter", "get-tuple-element", "bitcast")]
+
+
+def test_fred_fetch_transformer_fleet(one_chip, monkeypatch):
+    """The fused FRED step on the transformer fleet that
+    `benchmarks/lm_training.py` trains (tinyllama-1.1b at its smoke size,
+    λ = 16): the fetch compiles for the chip, its matrices written by the
+    row-write kernel within the VMEM budget, in as many launches as
+    `fetch_rows.launches` plans."""
+    from repro.configs import get_smoke_config
+    from repro.core.rules import ServerConfig
+    from repro.kernels import fetch_rows as fr
+    from repro.models.lm import make_lm_loss
+    from repro.models.transformer import init_model
+    from repro.sim.fred import SimConfig
+
+    plans = []
+    plan = fr.launches
+    monkeypatch.setattr(fr, "launches",
+                        lambda *a, **k: plans.append(plan(*a, **k))
+                        or plans[-1])
+    arch = get_smoke_config("tinyllama-1.1b")
+    params = init_model(jax.random.PRNGKey(0), arch)
+    tokens = jnp.zeros((64, 32), jnp.int32)
+    cfg = SimConfig(num_clients=16, batch_size=4, events_per_step=8,
+                    apply_mode="fused",
+                    server=ServerConfig(rule="asgd", lr=0.1,
+                                        kernel_interpret=False))
+    step = build_step_fn(cfg, make_lm_loss(arch), tokens, tokens)
+    state = jax.tree.map(
+        lambda x: _abstract(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda p: init_sim(cfg, p), params))
+    keys = _abstract((1, cfg.events_per_step, 2), jnp.uint32, one_chip)
+    text = _kernel_text(lambda s, k: jax.lax.scan(step, s, k), state, keys)
+    planned, = plans
+    assert planned
+    assert len(_launches(text, "fetch_rows")) == len(planned)
 
 
 @pytest.mark.parametrize("axis", ["server", "clients"])
